@@ -69,7 +69,9 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
             payload_compression.run(rounds=60, theta=30, keeps=(0.10,),
                                     time_rounds=20, out_path=None)
 
-    # sharded engine scaling (spawns fake-device workers; CPU-sized grid)
+    # sharded engine scaling: spawns fake-CPU-device workers, CPU-only by
+    # construction (JAX_PLATFORMS=cpu) — this parent has already imported
+    # JAX, and on a TPU host it holds the chip
     sharded_rounds.run(quick=not args.full)
 
     if args.full:

@@ -7,7 +7,10 @@ four strategies x {fp32, int8} wire formats at D in {1, 2, 4, 8} devices.
 
 CPU has one physical device, and ``--xla_force_host_platform_device_count``
 only takes effect before jax initializes — so every D runs in its own worker
-subprocess with fake CPU devices. Fake devices share the host's cores:
+subprocess with fake CPU devices. The workers are CPU-only
+(``JAX_PLATFORMS=cpu``) even on a TPU host, where the parent holds the chip
+and a child reaching for it would fail or hang. Fake devices share the
+host's cores:
 rounds/sec at D>1 measures the *overhead* of the sharded program
 (collectives + smaller per-device batches on shared silicon), not a
 speedup — the speedup story is the per-device numbers: each device holds

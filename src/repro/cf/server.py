@@ -34,6 +34,7 @@ from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.lax import optimization_barrier
 
 from repro.cf.local import solve_user_factors
 from repro.cf.model import CFConfig
@@ -51,7 +52,6 @@ from repro.core.selector import (
 )
 from repro.kernels import ops
 from repro.obs.telemetry import RoundTelemetry
-from repro.utils.compat import optimization_barrier
 from repro.optim.adam import (
     AdamConfig, AdamState, adam_init, adam_update_rows,
     adam_update_rows_scattered,

@@ -123,7 +123,7 @@ def compute_rewards(
         # pin the EMA's fusion boundary (see kernels.ops.RowOps): the same
         # expression must compile identically whether a resident or a
         # shard-local scatter consumes it
-        from repro.utils.compat import optimization_barrier
+        from jax.lax import optimization_barrier
         v_new = optimization_barrier(v_new)
 
     if mode == "geometric":

@@ -75,6 +75,7 @@ from repro.faults import (
 from repro.core.selector import (
     STRATEGIES, SelectorConfig, selector_counts,
 )
+from repro.kernels.ops import fit_block_m
 from repro.obs.config import ObsConfig
 from repro.obs.telemetry import (
     make_row_emitter, telemetry_round, telemetry_state_init,
@@ -130,9 +131,10 @@ class FLSimConfig:
     # bounds the (B, M) score matrix at web-scale M
     eval_user_chunk: Optional[int] = None
     # item-block size for the fused chunked scorer during periodic eval
-    # (kernels.wire_topn — no (B, M) score matrix). None = auto: engage at
-    # block 4096 whenever eval_user_chunk is set, else keep the one-shot
-    # dense path. Bit-identical either way (tested in test_serving.py).
+    # (kernels.wire_topn — no (B, M) score matrix). None = auto: whenever
+    # eval_user_chunk is set, engage at the largest block whose scoring tile
+    # fits VMEM for that chunk and K (kernels.ops.fit_block_m), else keep
+    # the one-shot dense path. Bit-identical either way (test_serving.py).
     eval_item_chunk: Optional[int] = None
     # "scan" (default engine) | "python" (reference) | "shard" (shard_map
     # data-parallel rounds over a ("data",) device mesh) | "async"
@@ -547,7 +549,6 @@ def make_sharded_round_runner(train_j: jax.Array, setup: _SimSetup,
 
     from repro.launch.mesh import make_data_mesh
     from repro.launch.sharding import fcf_state_pspecs, to_shardings
-    from repro.utils.compat import shard_map
 
     d = config.mesh_shards or len(jax.devices())
     m = setup.cf_cfg.num_items
@@ -615,7 +616,7 @@ def make_sharded_round_runner(train_j: jax.Array, setup: _SimSetup,
                     body, (state, tel), (cohorts_blk, stale))
                 return state, tel, ys, rows
 
-            run = jax.jit(shard_map(
+            run = jax.jit(jax.shard_map(
                 chunk, mesh=mesh,
                 in_specs=(state_specs, tel_specs,
                           P(None, "data", None), P(), P()),
@@ -641,7 +642,7 @@ def make_sharded_round_runner(train_j: jax.Array, setup: _SimSetup,
                     body, (state, tel), cohorts_blk)
                 return state, tel, ys, rows
 
-            run = jax.jit(shard_map(
+            run = jax.jit(jax.shard_map(
                 chunk, mesh=mesh,
                 in_specs=(state_specs, tel_specs, P(None, "data", None), P()),
                 out_specs=(state_specs, tel_specs, aux_specs, P()),
@@ -688,7 +689,7 @@ def make_sharded_round_runner(train_j: jax.Array, setup: _SimSetup,
 
             return jax.lax.scan(body, state, (cohorts_blk, stale, rf))
 
-        run = jax.jit(shard_map(
+        run = jax.jit(jax.shard_map(
             chunk, mesh=mesh,
             in_specs=(state_specs, P(None, "data", None), P(), P(), P()),
             out_specs=(state_specs, aux_specs), check_vma=False))
@@ -708,7 +709,7 @@ def make_sharded_round_runner(train_j: jax.Array, setup: _SimSetup,
 
             return jax.lax.scan(body, state, (cohorts_blk, rf))
 
-        run = jax.jit(shard_map(
+        run = jax.jit(jax.shard_map(
             chunk, mesh=mesh,
             in_specs=(state_specs, P(None, "data", None), P(), P()),
             out_specs=(state_specs, aux_specs), check_vma=False))
@@ -728,7 +729,7 @@ def make_sharded_round_runner(train_j: jax.Array, setup: _SimSetup,
 
             return jax.lax.scan(body, state, (cohorts_blk, stale))
 
-        run = jax.jit(shard_map(
+        run = jax.jit(jax.shard_map(
             chunk, mesh=mesh,
             in_specs=(state_specs, P(None, "data", None), P(), P()),
             out_specs=(state_specs, aux_specs), check_vma=False))
@@ -747,7 +748,7 @@ def make_sharded_round_runner(train_j: jax.Array, setup: _SimSetup,
 
             return jax.lax.scan(body, state, cohorts_blk)
 
-        run = jax.jit(shard_map(
+        run = jax.jit(jax.shard_map(
             chunk, mesh=mesh,
             in_specs=(state_specs, P(None, "data", None), P()),
             out_specs=(state_specs, aux_specs), check_vma=False))
@@ -769,9 +770,6 @@ def make_sharded_round_runner(train_j: jax.Array, setup: _SimSetup,
     return run_chunk, state0
 
 
-_EVAL_ITEM_CHUNK = 4096     # auto item-block when eval_user_chunk is set
-
-
 def _evaluate(q: jax.Array, eval_train: jax.Array, eval_test: jax.Array,
               config: FLSimConfig) -> RecMetrics:
     """Full-model eval, optionally chunked over users (bounded memory).
@@ -787,7 +785,7 @@ def _evaluate(q: jax.Array, eval_train: jax.Array, eval_test: jax.Array,
     n = eval_train.shape[0]
     item_chunk = config.eval_item_chunk
     if item_chunk is None and chunk is not None:
-        item_chunk = _EVAL_ITEM_CHUNK
+        item_chunk = fit_block_m(min(chunk, n), q.shape[1], top_n=10)
     if chunk is None or chunk >= n:
         return evaluate_users(q, eval_train, eval_test,
                               l2=config.l2, alpha=config.alpha,

@@ -31,12 +31,16 @@ def _fcf_grad_kernel(p_ref, q_ref, x_ref, out_ref, *, alpha: float, l2: float,
     q = q_ref[...].astype(jnp.float32)          # (bm, K)
     x = x_ref[...].astype(jnp.float32)          # (B, bm)
 
+    # float32-accurate MXU passes on the TPU (interpret mode ignores it)
+    hi = jax.lax.Precision.HIGHEST
     pred = jax.lax.dot_general(                  # (B, bm) = P @ q_blk^T
-        p, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        p, q, (((1,), (1,)), ((), ())), precision=hi,
+        preferred_element_type=jnp.float32)
     err = x - pred
     weighted = (1.0 + alpha * x) * err           # confidence-weighted residual
     grad = jax.lax.dot_general(                  # (bm, K) = weighted^T @ P
-        weighted, p, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        weighted, p, (((0,), (0,)), ((), ())), precision=hi,
+        preferred_element_type=jnp.float32)
     out_ref[...] = (-2.0 * grad + (2.0 * l2 * batch) * q).astype(out_ref.dtype)
 
 

@@ -15,7 +15,8 @@ With int8 Adam moments the sparse commit's per-row hot path becomes
 The fp32 moments of the full (M, K) table are never materialized — the
 whole point of compressed state. Same structure as
 :mod:`repro.kernels.payload_quant`: one grid step per selected row,
-scalar-prefetched indices steering the row DMA, (1, K) blocks in VMEM.
+scalar-prefetched indices steering the row DMA, (1, 1, K) blocks over the
+(M, 1, K) row view.
 
 BIT-EXACTNESS CONTRACT: the quantization math must reproduce
 :func:`repro.compress.codecs.quantize_rows` /
@@ -35,6 +36,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.compress.codecs import _QMAX as _CODEC_QMAX
+from repro.kernels.payload_gather import (
+    _UNREAD, _at_index, _at_step, _row_view,
+)
 
 _QMAX = float(_CODEC_QMAX[8])      # symmetric int8 grid, shared w/ codec
 
@@ -49,7 +53,7 @@ PARITY_ORACLES = {
 
 
 def _gather_dequant_kernel(idx_ref, codes_ref, scales_ref, out_ref):
-    # codes/scales blocks are the (1, K) / (1, 1) rows at idx[i]
+    # codes/scales blocks are the (1, 1, K) / (1, 1, 1) rows at idx[i]
     del idx_ref
     out_ref[...] = codes_ref[...].astype(jnp.float32) * scales_ref[...]
 
@@ -72,18 +76,16 @@ def gather_dequant_rows(
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(m_s,),
-        in_specs=[
-            pl.BlockSpec((1, k), lambda i, idx_ref: (idx_ref[i], 0)),
-            pl.BlockSpec((1, 1), lambda i, idx_ref: (idx_ref[i], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, k), lambda i, idx_ref: (i, 0)),
+        in_specs=[_at_index(k), _at_index(1)],
+        out_specs=_at_step(k),
     )
     return pl.pallas_call(
         _gather_dequant_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m_s, k), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((m_s, 1, k), jnp.float32),
         interpret=interpret,
-    )(idx.astype(jnp.int32), codes, scales)
+    )(idx.astype(jnp.int32), _row_view(codes), _row_view(scales)).reshape(
+        m_s, k)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -110,7 +112,7 @@ def _quant_scatter_kernel(idx_ref, rows_ref, codes_in, scales_in,
     # aliased in/out: overwrite the stored row with the requantized tile.
     del idx_ref, codes_in, scales_in
     row = rows_ref[...].astype(jnp.float32)
-    absmax = jnp.max(jnp.abs(row), axis=-1, keepdims=True)      # (1, 1)
+    absmax = jnp.max(jnp.abs(row), axis=-1, keepdims=True)      # (1, 1, 1)
     scale = absmax * (1.0 / _QMAX)   # matches codecs.quantize_rows exactly
     inv = jnp.where(scale > 0, 1.0 / scale, 0.0)
     codes_out[...] = jnp.clip(
@@ -123,7 +125,7 @@ def _quant_scatter_sr_kernel(idx_ref, rows_ref, noise_ref, codes_in,
     # stochastic variant: floor(x/scale + u) — codecs.quantize_rows_stochastic
     del idx_ref, codes_in, scales_in
     row = rows_ref[...].astype(jnp.float32)
-    absmax = jnp.max(jnp.abs(row), axis=-1, keepdims=True)      # (1, 1)
+    absmax = jnp.max(jnp.abs(row), axis=-1, keepdims=True)      # (1, 1, 1)
     scale = absmax * (1.0 / _QMAX)
     inv = jnp.where(scale > 0, 1.0 / scale, 0.0)
     codes_out[...] = jnp.clip(
@@ -151,41 +153,28 @@ def quant_scatter_set_rows(
     """
     m_s = idx.shape[0]
     k = codes.shape[1]
-    row_spec = pl.BlockSpec((1, k), lambda i, idx_ref: (i, 0))
-    codes_spec = pl.BlockSpec((1, k), lambda i, idx_ref: (idx_ref[i], 0))
-    scales_spec = pl.BlockSpec((1, 1), lambda i, idx_ref: (idx_ref[i], 0))
     out_shape = (
-        jax.ShapeDtypeStruct(codes.shape, jnp.int8),
-        jax.ShapeDtypeStruct(scales.shape, jnp.float32),
+        jax.ShapeDtypeStruct(_row_view(codes).shape, jnp.int8),
+        jax.ShapeDtypeStruct(_row_view(scales).shape, jnp.float32),
     )
-    out_specs = [codes_spec, scales_spec]
-    if noise is None:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(m_s,),
-            in_specs=[row_spec, codes_spec, scales_spec],
-            out_specs=out_specs,
-        )
-        return pl.pallas_call(
-            _quant_scatter_kernel,
-            grid_spec=grid_spec,
-            out_shape=out_shape,
-            # alias codes/scales operands (args: idx, rows, codes, scales)
-            input_output_aliases={2: 0, 3: 1},
-            interpret=interpret,
-        )(idx.astype(jnp.int32), rows, codes, scales)
+    # the payload tile (and the dither, when given) streams per grid step;
+    # the resident codes/scales are only written, at idx[i]
+    tiles = [rows] if noise is None else [rows, noise]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1, grid=(m_s,),
-        in_specs=[row_spec, row_spec, codes_spec, scales_spec],
-        out_specs=out_specs,
+        in_specs=[_at_step(k)] * len(tiles) + [_UNREAD, _UNREAD],
+        out_specs=[_at_index(k), _at_index(1)],
     )
-    return pl.pallas_call(
-        _quant_scatter_sr_kernel,
+    n = 1 + len(tiles)                 # args: idx, *tiles, codes, scales
+    new_codes, new_scales = pl.pallas_call(
+        _quant_scatter_kernel if noise is None else _quant_scatter_sr_kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        # alias codes/scales operands (args: idx, rows, noise, codes, scales)
-        input_output_aliases={3: 0, 4: 1},
+        input_output_aliases={n: 0, n + 1: 1},
         interpret=interpret,
-    )(idx.astype(jnp.int32), rows, noise, codes, scales)
+    )(idx.astype(jnp.int32), *map(_row_view, tiles), _row_view(codes),
+      _row_view(scales))
+    return new_codes.reshape(codes.shape), new_scales.reshape(scales.shape)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",),

@@ -183,7 +183,7 @@ class RowOps(NamedTuple):
 
 def default_row_ops() -> RowOps:
     """Row ops over a fully-resident table (the single-device hot path)."""
-    from repro.utils.compat import optimization_barrier
+    from jax.lax import optimization_barrier
 
     def gather(table: jax.Array, idx: jax.Array) -> jax.Array:
         return optimization_barrier(gather_rows(table, idx))
@@ -199,6 +199,33 @@ def dequant_scatter_set_rows(
         return _ref.dequant_scatter_set_rows_ref(table, idx, values, scales)
     return _pq.dequant_scatter_set_rows(table, idx, values, scales,
                                         interpret=_interpret())
+
+
+# the v5e's default scoped-VMEM limit: what one kernel invocation may hold
+_VMEM_BUDGET = 16 * 1024 * 1024
+
+
+def _lanes(n: int) -> int:
+    return -(-n // 128) * 128
+
+
+def fit_block_m(batch: int, dim: int, top_n: int) -> int:
+    """Largest item block (a power of two in [128, 4096]) whose scoring tile
+    fits the scoped VMEM at this batch and row width.
+
+    A block holds ~8 live (B, block_m + N) 32-bit arrays (the masked score
+    tile, its double-buffered mask block, and the merge's candidate/select
+    temporaries) plus ~4 (block_m, K) 32-bit arrays (the double-buffered
+    wire block and its dequantized copy), each padded to whole (8, 128)
+    tiles. The estimate is conservative: it admits block 1024 and refuses
+    2048 at B=256, K=25, where the compiler accepts 2048 and refuses 4096.
+    """
+    b8 = -(-batch // 8) * 8
+    block = 4096
+    while block > 128 and 4 * (8 * b8 * _lanes(block + top_n)
+                               + 4 * block * _lanes(dim)) > _VMEM_BUDGET:
+        block //= 2
+    return block
 
 
 def wire_topn(

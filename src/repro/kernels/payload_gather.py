@@ -4,10 +4,16 @@ The payload subset operations are the per-round hot path of the FL server:
   * download: Q* = Q[idx]            (gather M_s of M rows)
   * upload:   Q[idx] += grad_rows    (scatter-add aggregated gradients)
 
-For LLM-scale tables (256k x 5120) these run every round; blocking them
-keeps only (block_rows, K) tiles in VMEM and uses scalar prefetch so the
-row indices are available to the index_map before the DMA is issued —
-the TPU-native equivalent of the paper's "subset the Q factor matrix".
+For LLM-scale tables (256k x 5120) these run every round; each grid step
+moves one row, and scalar prefetch makes the row indices available to the
+index_map before the DMA is issued — the TPU-native equivalent of the
+paper's "subset the Q factor matrix".
+
+ROW VIEW. Mosaic requires a block's last two dims to be multiples of
+(8, 128) or equal to the array's, so a (1, K) row block of an (M, K) table
+is refused for every K the repo uses (25, 16). Every row kernel therefore
+runs over the free reshape ``(M, K) -> (M, 1, K)`` (:func:`_row_view`) with
+``(1, 1, K)`` blocks, whose last two dims equal the array's.
 
 Note on scatter semantics: indices are assumed UNIQUE (payload selections
 are top-k / choice-without-replacement, so this holds by construction).
@@ -24,8 +30,28 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def _row_view(a: jax.Array) -> jax.Array:
+    """(M, K) -> (M, 1, K): the layout every row kernel blocks over."""
+    return a.reshape(a.shape[0], 1, a.shape[1])
+
+
+def _at_index(width: int) -> pl.BlockSpec:
+    """The (1, 1, width) row block at ``idx[i]`` (scalar-prefetched)."""
+    return pl.BlockSpec((1, 1, width), lambda i, idx_ref: (idx_ref[i], 0, 0))
+
+
+def _at_step(width: int) -> pl.BlockSpec:
+    """The (1, 1, width) row block at grid step ``i`` (the payload side)."""
+    return pl.BlockSpec((1, 1, width), lambda i, idx_ref: (i, 0, 0))
+
+
+# an aliased output's input operand that the kernel never reads: left in
+# place (no DMA), every row the grid does not write keeps its value
+_UNREAD = pl.BlockSpec(memory_space=pl.ANY)
+
+
 def _gather_kernel(idx_ref, table_ref, out_ref):
-    # table_ref block is (1, K) at row idx[i] — selected by the index_map.
+    # table_ref block is (1, 1, K) at row idx[i] — selected by the index_map.
     out_ref[...] = table_ref[...]
 
 
@@ -42,15 +68,15 @@ def gather_rows(
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(m_s,),
-        in_specs=[pl.BlockSpec((1, k), lambda i, idx_ref: (idx_ref[i], 0))],
-        out_specs=pl.BlockSpec((1, k), lambda i, idx_ref: (i, 0)),
+        in_specs=[_at_index(k)],
+        out_specs=_at_step(k),
     )
     return pl.pallas_call(
         _gather_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m_s, k), table.dtype),
+        out_shape=jax.ShapeDtypeStruct((m_s, 1, k), table.dtype),
         interpret=interpret,
-    )(idx.astype(jnp.int32), table)
+    )(idx.astype(jnp.int32), _row_view(table)).reshape(m_s, k)
 
 
 def _scatter_set_kernel(idx_ref, rows_ref, table_in_ref, out_ref):
@@ -78,20 +104,18 @@ def scatter_set_rows(
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(m_s,),
-        in_specs=[
-            pl.BlockSpec((1, k), lambda i, idx_ref: (i, 0)),           # rows
-            pl.BlockSpec((1, k), lambda i, idx_ref: (idx_ref[i], 0)),  # table
-        ],
-        out_specs=pl.BlockSpec((1, k), lambda i, idx_ref: (idx_ref[i], 0)),
+        in_specs=[_at_step(k), _UNREAD],          # rows, table
+        out_specs=_at_index(k),
     )
     return pl.pallas_call(
         _scatter_set_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
+        out_shape=jax.ShapeDtypeStruct(_row_view(table).shape, table.dtype),
         # alias the table operand (positional arg 2: idx, rows, table)
         input_output_aliases={2: 0},
         interpret=interpret,
-    )(idx.astype(jnp.int32), rows, table)
+    )(idx.astype(jnp.int32), _row_view(rows), _row_view(table)).reshape(
+        table.shape)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -107,7 +131,7 @@ def gather_rows_block(
     global payload indices to ``idx - shard_offset`` and every shard gathers
     a full (M_s, K) candidate block — rows it does not own come from the
     clamp and are discarded by the owner-select after the all-gather
-    (:func:`repro.kernels.ops.assemble_rows`). Clamping instead of masking
+    (:func:`repro.cf.server.assemble_rows`). Clamping instead of masking
     keeps the kernel identical to :func:`gather_rows` (one indexed row DMA
     per grid step) with no divergent control flow.
     """
@@ -172,17 +196,15 @@ def scatter_add_rows(
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(m_s,),
-        in_specs=[
-            pl.BlockSpec((1, k), lambda i, idx_ref: (i, 0)),           # rows
-            pl.BlockSpec((1, k), lambda i, idx_ref: (idx_ref[i], 0)),  # table
-        ],
-        out_specs=pl.BlockSpec((1, k), lambda i, idx_ref: (idx_ref[i], 0)),
+        in_specs=[_at_step(k), _at_index(k)],     # rows, table
+        out_specs=_at_index(k),
     )
     return pl.pallas_call(
         _scatter_add_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
+        out_shape=jax.ShapeDtypeStruct(_row_view(table).shape, table.dtype),
         # alias the table operand (positional arg 2: idx, rows, table)
         input_output_aliases={2: 0},
         interpret=interpret,
-    )(idx.astype(jnp.int32), rows, table)
+    )(idx.astype(jnp.int32), _row_view(rows), _row_view(table)).reshape(
+        table.shape)

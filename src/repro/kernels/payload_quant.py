@@ -15,7 +15,7 @@ With a quantized wire format the FL server's per-round hot path becomes:
 
 Same structure as :mod:`repro.kernels.payload_gather`: one grid step per
 selected row, scalar-prefetched indices so the index_map can steer the row
-DMA, (1, K) blocks in VMEM.
+DMA, (1, 1, K) blocks over the (M, 1, K) row view.
 
 BIT-EXACTNESS CONTRACT: the quantization math here must reproduce
 :func:`repro.compress.codecs.quantize_rows` / ``dequantize_rows``
@@ -35,14 +35,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.compress.codecs import _QMAX as _CODEC_QMAX
+from repro.kernels.payload_gather import (
+    _UNREAD, _at_index, _at_step, _row_view,
+)
 
 _QMAX = float(_CODEC_QMAX[8])      # symmetric int8 grid, shared w/ codec
 
 
 def _gather_quant_kernel(idx_ref, table_ref, values_ref, scales_ref):
-    # table_ref block is (1, K) at row idx[i] — selected by the index_map.
+    # table_ref block is (1, 1, K) at row idx[i] — selected by the index_map.
     row = table_ref[...].astype(jnp.float32)
-    absmax = jnp.max(jnp.abs(row), axis=-1, keepdims=True)      # (1, 1)
+    absmax = jnp.max(jnp.abs(row), axis=-1, keepdims=True)      # (1, 1, 1)
     scale = absmax * (1.0 / _QMAX)   # matches codecs.quantize_rows exactly
     inv = jnp.where(scale > 0, 1.0 / scale, 0.0)
     values_ref[...] = jnp.clip(
@@ -68,21 +71,19 @@ def gather_quantize_rows(
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(m_s,),
-        in_specs=[pl.BlockSpec((1, k), lambda i, idx_ref: (idx_ref[i], 0))],
-        out_specs=[
-            pl.BlockSpec((1, k), lambda i, idx_ref: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, idx_ref: (i, 0)),
-        ],
+        in_specs=[_at_index(k)],
+        out_specs=[_at_step(k), _at_step(1)],
     )
-    return pl.pallas_call(
+    codes, scales = pl.pallas_call(
         _gather_quant_kernel,
         grid_spec=grid_spec,
         out_shape=(
-            jax.ShapeDtypeStruct((m_s, k), jnp.int8),
-            jax.ShapeDtypeStruct((m_s, 1), jnp.float32),
+            jax.ShapeDtypeStruct((m_s, 1, k), jnp.int8),
+            jax.ShapeDtypeStruct((m_s, 1, 1), jnp.float32),
         ),
         interpret=interpret,
-    )(idx.astype(jnp.int32), table)
+    )(idx.astype(jnp.int32), _row_view(table))
+    return codes.reshape(m_s, k), scales.reshape(m_s, 1)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -135,18 +136,15 @@ def dequant_scatter_set_rows(
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(m_s,),
-        in_specs=[
-            pl.BlockSpec((1, k), lambda i, idx_ref: (i, 0)),           # values
-            pl.BlockSpec((1, 1), lambda i, idx_ref: (i, 0)),           # scales
-            pl.BlockSpec((1, k), lambda i, idx_ref: (idx_ref[i], 0)),  # table
-        ],
-        out_specs=pl.BlockSpec((1, k), lambda i, idx_ref: (idx_ref[i], 0)),
+        in_specs=[_at_step(k), _at_step(1), _UNREAD],  # values, scales, table
+        out_specs=_at_index(k),
     )
     return pl.pallas_call(
         _dequant_scatter_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
+        out_shape=jax.ShapeDtypeStruct(_row_view(table).shape, table.dtype),
         # alias the table operand (positional arg 3: idx, values, scales, table)
         input_output_aliases={3: 0},
         interpret=interpret,
-    )(idx.astype(jnp.int32), values, scales, table)
+    )(idx.astype(jnp.int32), _row_view(values), _row_view(scales),
+      _row_view(table)).reshape(table.shape)

@@ -25,12 +25,12 @@ fuse all three stages over item blocks:
 BIT-EXACTNESS CONTRACT (same shape as payload_quant's): dequantization
 reproduces :mod:`repro.compress.codecs` op-for-op, scores reduce over K
 only (item blocking cannot reorder a dot product), and the merge preserves
-top_k tie order — so fp32/fp16/int8 results are bit-identical to
-``ref.wire_topn_ref``, values AND indices AND order. int4 shares the exact
-unpack sequence but its unpack->dequant->matmul chain may fuse differently
-under Mosaic on real TPUs; parity there is documented-ulp (exact in
-interpret mode, allclose on hardware) — same caveat class as the round
-engine's int4 note. The topk wire format has no kernel (scoring a sparse
+top_k tie order — so fp32/fp16/int8/int4 results are bit-identical to
+``ref.wire_topn_ref``, values AND indices AND order, in interpret mode. On
+the TPU the score matmul runs at ``Precision.HIGHEST`` (float32 accuracy);
+XLA's own default for an f32 dot is coarser, so the on-chip oracle is run
+under ``jax.default_matmul_precision("highest")`` and agrees to float32
+rounding, not bit-for-bit. The topk wire format has no kernel (scoring a sparse
 wire is a scatter, not a block dequant) and always routes through the ref.
 
 Masking uses the metrics module's ``NEG_INF`` (-1e30) sentinel, so a
@@ -60,13 +60,27 @@ NEG_INF = -1e30     # train-mask sentinel, shared with repro.cf.metrics
 
 
 def _unpack_int4_block(packed: jax.Array, dim: int) -> jax.Array:
-    """In-VMEM nibble unpack, op-for-op ``codecs.unpack_int4``."""
-    lo = (packed & 0xF).astype(jnp.int8)
-    hi = ((packed >> 4) & 0xF).astype(jnp.int8)
-    lo = jnp.where(lo > 7, lo - 16, lo)
-    hi = jnp.where(hi > 7, hi - 16, hi)
-    codes = jnp.stack([lo, hi], axis=-1).reshape(packed.shape[0], -1)
-    return codes[:, :dim]
+    """In-VMEM nibble unpack to float32 codes, value-for-value
+    ``codecs.unpack_int4``.
+
+    Byte j holds code 2j in its low nibble and code 2j+1 in its high one
+    (``codecs.pack_int4``). Interleaving the two halves with a stack and a
+    reshape is a shape cast Mosaic refuses, so each half is placed into its
+    columns by a 0/1 matrix product instead: every output is one code (an
+    integer in [-7, 7]) times 1.0 plus zeros, exact at any matmul precision.
+    """
+    nib = packed.astype(jnp.int32)
+    lo = nib & 0xF
+    hi = (nib >> 4) & 0xF
+    lo = jnp.where(lo > 7, lo - 16, lo).astype(jnp.float32)
+    hi = jnp.where(hi > 7, hi - 16, hi).astype(jnp.float32)
+    shape = (packed.shape[1], dim)
+    byte = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    even = (col == 2 * byte).astype(jnp.float32)
+    odd = (col == 2 * byte + 1).astype(jnp.float32)
+    return (jnp.dot(lo, even, preferred_element_type=jnp.float32)
+            + jnp.dot(hi, odd, preferred_element_type=jnp.float32))
 
 
 def _merge_topn(vals, idxs, s, gidx, top_n: int):
@@ -115,9 +129,9 @@ def _make_score_kernel(kind: str, masked: bool, num_rows: int, dim: int,
         if kind == "int4":
             codes = _unpack_int4_block(codes_ref[...], dim)
         else:
-            codes = codes_ref[...]
+            codes = codes_ref[...].astype(jnp.float32)
         # op-for-op codecs.dequantize_rows: codes f32 * per-row f32 scale
-        return codes.astype(jnp.float32) * scales_ref[...]
+        return codes * scales_ref[...]
 
     def kernel(*refs):
         p_ref = refs[0]
@@ -133,6 +147,7 @@ def _make_score_kernel(kind: str, masked: bool, num_rows: int, dim: int,
 
         q = dequant(wire_refs)                                  # (bm, K) f32
         s = jnp.dot(p_ref[...].astype(jnp.float32), q.T,
+                    precision=jax.lax.Precision.HIGHEST,
                     preferred_element_type=jnp.float32)         # (B, bm)
         b = s.shape[0]
         gidx = j * block_m + jax.lax.broadcasted_iota(
@@ -226,6 +241,6 @@ def quant4_topn(
     block_m: int = 1024,
     interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Fused int4 unpack+dequant+score+top-N (documented-ulp tier)."""
+    """Fused int4 unpack+dequant+score+top-N."""
     return _call_topn("int4", p, (packed, scales), mask, top_n, block_m,
                       interpret, packed.shape[0], dim)

@@ -35,12 +35,17 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.configs.registry import get_config, list_archs
 from repro.configs.shapes import INPUT_SHAPES, InputShape
-from repro.launch.hlo_analysis import collective_bytes, roofline_terms
+from repro.launch.hlo_analysis import (
+    collective_bytes, peaks_for, roofline_terms,
+)
 from repro.launch.mesh import batch_axes, data_axis_size, make_production_mesh
 from repro.launch.sharding import input_pspecs, param_pspecs, to_shardings
 from repro.models import lm
 
 _KEY_SPEC = jax.ShapeDtypeStruct((2,), jnp.uint32)
+# the chip the production mesh is made of (launch/mesh.py: v5e pod slices);
+# the dry-run's placeholder devices are CPUs, so the target is named here
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 
 def skip_reason(cfg: ModelConfig, shape: InputShape) -> Optional[str]:
@@ -287,7 +292,8 @@ def run_pair(arch: str, shape_name: str, *, multi_pod: bool,
                "coll": float(coll["total"])}
     corrected = corrected_costs(cfg, shape, mesh, builder, scanned)
     terms = roofline_terms(corrected["flops"], corrected["bytes"],
-                           corrected["coll"], num_chips)
+                           corrected["coll"], num_chips,
+                           **peaks_for(TARGET_DEVICE_KIND))
 
     n_params = cfg.param_count()
     n_active = cfg.active_param_count()

@@ -107,15 +107,31 @@ def collective_bytes(hlo_text: str, while_trip: int = 1) -> Dict[str, int]:
     return out
 
 
+# Published per-chip peaks keyed by ``jax.Device.device_kind`` (Google
+# Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM,
+# 1,600 Gbit/s of interconnect over 4 links = 50 GB/s per link).
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"peak_flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+
+
+def peaks_for(device_kind: str) -> Dict[str, float]:
+    """The :data:`PEAKS` entry for ``device_kind``; unknown kinds raise."""
+    if device_kind not in PEAKS:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}")
+    return dict(PEAKS[device_kind])
+
+
 def roofline_terms(
     flops: float,
     bytes_accessed: float,
     coll_bytes: float,
     num_chips: int,
     *,
-    peak_flops: float = 197e12,      # TPU v5e bf16 per chip
-    hbm_bw: float = 819e9,           # bytes/s per chip
-    link_bw: float = 50e9,           # bytes/s per ICI link
+    peak_flops: float,               # FLOP/s per chip   (see peaks_for)
+    hbm_bw: float,                   # bytes/s per chip
+    link_bw: float,                  # bytes/s per ICI link
 ) -> Dict[str, float]:
     """The three roofline terms (seconds) + dominant bottleneck.
 

@@ -24,7 +24,10 @@ import numpy as np
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: shardings come from the jit in/out specs and the models'
+    # sharding hints, not from Explicit-axis types on every array
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_data_mesh(num_shards: Optional[int] = None) -> jax.sharding.Mesh:
@@ -54,12 +57,15 @@ def fake_cpu_devices_env(num_devices: int,
     (dropping any previous setting of that flag). The flag only takes effect
     before the first jax initialization, hence the subprocess pattern used by
     ``tests/test_sharded_rounds.py`` and ``benchmarks/sharded_rounds.py``.
+    ``JAX_PLATFORMS=cpu`` keeps the child off any accelerator: a chip belongs
+    to one process at a time, and on a TPU host the parent may hold it.
     """
     env = dict(os.environ if env is None else env)
     kept = [f for f in env.get("XLA_FLAGS", "").split()
             if not f.startswith(_FAKE_CPU_FLAG)]
     kept.append(f"{_FAKE_CPU_FLAG}={int(num_devices)}")
     env["XLA_FLAGS"] = " ".join(kept)
+    env["JAX_PLATFORMS"] = "cpu"
     return env
 
 

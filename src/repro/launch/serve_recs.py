@@ -227,4 +227,7 @@ def main(argv: Optional[List[str]] = None) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     main()

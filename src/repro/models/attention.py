@@ -6,10 +6,10 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
 from repro.kernels import ops
 from repro.utils import hints
-from repro.utils.compat import shard_map
 from repro.models.layers import _he, apply_rope, init_rmsnorm, rmsnorm
 
 

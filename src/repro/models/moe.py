@@ -17,10 +17,10 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
 from repro.models.layers import _he
 from repro.utils import hints
-from repro.utils.compat import shard_map
 
 
 def init_moe(key, d_model: int, d_ff: int, num_experts: int, dtype=jnp.float32):

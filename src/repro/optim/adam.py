@@ -213,7 +213,7 @@ def adam_update_rows_scattered(
     # the moment/param math compiles identically no matter which scatter
     # flavor (resident vs shard-local) consumes it — the bit-parity contract
     # between the sharded and single-device round engines
-    from repro.utils.compat import optimization_barrier
+    from jax.lax import optimization_barrier
     m_rows, v_rows, new_rows = optimization_barrier(
         (m_rows, v_rows, new_rows))
 

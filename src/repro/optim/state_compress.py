@@ -175,7 +175,7 @@ def decode_moment_rows(dtype: str, mom: Any, indices: jax.Array,
     encoding makes the two bit-identical.
     """
     from repro.kernels import ops
-    from repro.utils.compat import optimization_barrier
+    from jax.lax import optimization_barrier
 
     if dtype == "bf16":
         raw = row_ops.gather(mom, indices)
@@ -260,7 +260,7 @@ def adam_update_rows_compressed(
     outer-product estimate ``r_hat[i] * c_hat[j] / mean(c_hat)``.
     """
     from repro.kernels import ops as kops
-    from repro.utils.compat import optimization_barrier
+    from jax.lax import optimization_barrier
 
     validate_config(moment)
     if needs_sr_key(moment) and key is None:

@@ -2,22 +2,21 @@
 sharded attention (shard_map over a 16-device mesh) must produce the same
 logits as the plain single-device decode.
 
-Runs in a subprocess because the sharded path needs
-XLA_FLAGS=--xla_force_host_platform_device_count and jax pins the device
-count at first init (the main pytest process must keep seeing 1 device).
+Runs in a subprocess of 16 fake CPU devices (``fake_cpu_devices_env``)
+because jax pins the device count at first init (the main pytest process
+must keep seeing 1 device).
 """
-import os
 import subprocess
 import sys
 
 import pytest
 
+from repro.launch.mesh import fake_cpu_devices_env
+
 _SCRIPT = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
 import jax, jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.configs.registry import get_config
 from repro.launch.sharding import input_pspecs, param_pspecs, to_shardings
@@ -36,7 +35,8 @@ tok1 = jax.random.randint(jax.random.PRNGKey(2), (B, 1), 0, cfg.vocab_size, jnp.
 logits_a, cache_a = lm.decode_step(params, cfg, cache, tok0, jnp.asarray(0, jnp.int32))
 ref_logits, _ = lm.decode_step(params, cfg, cache_a, tok1, jnp.asarray(1, jnp.int32))
 
-mesh = jax.make_mesh((2, 8), ("data", "model"))
+mesh = jax.make_mesh((2, 8), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 with mesh, hints.batch_axes(("data",), mesh=mesh, kv_time_shard=True):
     step = jax.jit(lambda p, c, t, pos: lm.decode_step(p, cfg, c, t, pos))
     logits_b, cache_b = step(params, cache, tok0, jnp.asarray(0, jnp.int32))
@@ -50,7 +50,7 @@ print("KV-SHARDED-DECODE-OK")
 
 @pytest.mark.slow
 def test_kv_sharded_decode_matches_reference():
-    env = dict(os.environ)
+    env = fake_cpu_devices_env(16)
     env["PYTHONPATH"] = "src"
     out = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
                          capture_output=True, text=True, timeout=900)

@@ -233,5 +233,19 @@ def test_fake_cpu_devices_env_replaces_previous_flag():
     env = fake_cpu_devices_env(4, env={"XLA_FLAGS": (
         "--xla_foo=1 --xla_force_host_platform_device_count=2")})
     assert "--xla_force_host_platform_device_count=4" in env["XLA_FLAGS"]
+    assert env["JAX_PLATFORMS"] == "cpu"     # the child never takes a chip
     assert "device_count=2" not in env["XLA_FLAGS"]
     assert "--xla_foo=1" in env["XLA_FLAGS"]
+
+
+def test_roofline_peaks_come_from_device_kind():
+    from repro.launch.hlo_analysis import peaks_for, roofline_terms
+
+    peaks = peaks_for("TPU v5 lite")
+    assert peaks["peak_flops"] == 197e12 and peaks["hbm_bw"] == 819e9
+    terms = roofline_terms(197e12, 2 * 819e9, 0.0, 1, **peaks)
+    assert terms["bottleneck"] == "memory" and terms["step_time_s"] == 2.0
+    with pytest.raises(ValueError, match="'cpu'"):
+        peaks_for("cpu")                      # unknown device: an error
+    with pytest.raises(TypeError):
+        roofline_terms(1.0, 1.0, 0.0, 1)      # no silent default peak
