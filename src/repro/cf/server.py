@@ -430,15 +430,18 @@ def server_round_step(
     down_cfg, up_cfg = direction_configs(codec_cfg)
     m_s = sel_cfg.num_select
     kdim = state.q.shape[1]
-    key, k_sel = jax.random.split(state.key)
 
     # lines 8-10: select the payload subset, gather + encode + "transmit" Q*;
     # clients decode the wire image, so q_star below is what they compute on
-    idx, sel = selector_select(sel_cfg, state.sel, k_sel)
-    q_star = decode(down_cfg, _downlink_wire(state.q, idx, down_cfg, shard),
-                    kdim)                                    # (M_s, K)
-    q_star = optimization_barrier(q_star)
-    bytes_down = state.bytes_down + wire_bytes(down_cfg, m_s, kdim)
+    with jax.named_scope("fl_select"):
+        key, k_sel = jax.random.split(state.key)
+        idx, sel = selector_select(sel_cfg, state.sel, k_sel)
+    with jax.named_scope("fl_downlink"):
+        q_star = decode(down_cfg,
+                        _downlink_wire(state.q, idx, down_cfg, shard),
+                        kdim)                                # (M_s, K)
+        q_star = optimization_barrier(q_star)
+        bytes_down = state.bytes_down + wire_bytes(down_cfg, m_s, kdim)
 
     # lines 11-18: cohort solve, uplink, Adam commit, reward feedback.
     # The stochastic-rounding dither key only exists when the moment config
@@ -453,19 +456,20 @@ def server_round_step(
             want_stats=telemetry,
             corrupt=faults.corrupt if has_corrupt else None,
             moment_key=moment_key)
-    per_user_bytes = wire_bytes(up_cfg, m_s, kdim)
-    if has_corrupt:
-        per_user_bytes += m_s * CHECKSUM_BYTES_PER_ROW
-    bytes_up = state.bytes_up + per_user_bytes * num_users
+    with jax.named_scope("fl_commit"):
+        per_user_bytes = wire_bytes(up_cfg, m_s, kdim)
+        if has_corrupt:
+            per_user_bytes += m_s * CHECKSUM_BYTES_PER_ROW
+        bytes_up = state.bytes_up + per_user_bytes * num_users
 
-    fault_state = state.faults
-    if faults is not None:
-        rejected = (jnp.zeros((), jnp.float32) if intact is None
-                    else jnp.sum(~intact).astype(jnp.float32))
-        fault_state = fault_state_update(
-            state.faults, faults.dropped, faults.stragglers, rejected,
-            rejected * float(wire_bytes(up_cfg, 1, kdim)
-                             + CHECKSUM_BYTES_PER_ROW))
+        fault_state = state.faults
+        if faults is not None:
+            rejected = (jnp.zeros((), jnp.float32) if intact is None
+                        else jnp.sum(~intact).astype(jnp.float32))
+            fault_state = fault_state_update(
+                state.faults, faults.dropped, faults.stragglers, rejected,
+                rejected * float(wire_bytes(up_cfg, 1, kdim)
+                                 + CHECKSUM_BYTES_PER_ROW))
 
     new_state = ServerState(
         q=q_new, opt=opt, sel=sel, key=key, t=state.t + 1,
@@ -575,87 +579,94 @@ def _commit_against(
 
     # line 11: every cohort user solves p_i on-device and uplinks gradients;
     # the server receives the cohort aggregate, assembled block-by-block
-    if callable(cohort_x):
-        x_blocks = cohort_x(idx)                 # (C, b, M_s) or (B, M_s)
-    else:
-        x_blocks = jnp.take(cohort_x, idx, axis=1)           # (B, M_s)
-    if x_blocks.ndim == 2:
-        x_blocks = x_blocks[None]                            # one block
+    with jax.named_scope("fl_gather"):
+        if callable(cohort_x):
+            x_blocks = cohort_x(idx)             # (C, b, M_s) or (B, M_s)
+        else:
+            x_blocks = jnp.take(cohort_x, idx, axis=1)       # (B, M_s)
+        if x_blocks.ndim == 2:
+            x_blocks = x_blocks[None]                        # one block
     if num_users is None:
         num_users = x_blocks.shape[0] * x_blocks.shape[1]
     parts = []
     for i in range(x_blocks.shape[0]):
-        p_i = solve_user_factors(q_star, x_blocks[i],
-                                 l2=cf_cfg.l2, alpha=cf_cfg.alpha)
+        with jax.named_scope("fl_solve"):
+            p_i = solve_user_factors(q_star, x_blocks[i],
+                                     l2=cf_cfg.l2, alpha=cf_cfg.alpha)
         # data term only (l2=0): the ridge term is applied once, below, with
         # the true cohort size — padded all-zero user rows solve to p=0 and
         # contribute exactly zero here
-        parts.append(ops.fcf_item_gradients(
-            q_star, p_i, x_blocks[i], alpha=cf_cfg.alpha, l2=0.0))
-    parts = jnp.stack(parts)                                 # (C, M_s, K)
-    if shard is not None:
-        # ordered psum: all-gather the per-device partials and reduce in
-        # fixed block order — bit-stable against the single-device scan
-        # over the same blocks (a raw lax.psum orders by topology)
-        parts = jax.lax.all_gather(parts, shard.axis, axis=0, tiled=True)
-    parts = optimization_barrier(parts)
-    grads = (jnp.sum(parts, axis=0)
-             + 2.0 * cf_cfg.l2 * num_users * q_star)         # (M_s, K)
-
-    # uplink encode (+ error feedback for stateful codecs): the server only
-    # ever sees the decoded wire image of the aggregated gradient
-    codec_state = state.codec
-    intact = None
-    if corrupt is not None:
-        # payload integrity path: checksum the encoded wire, flip the
-        # scheduled rows' bits in transit, reject rows whose received image
-        # no longer matches. Rejected rows keep their full effective
-        # gradient in the residual so the next round's encode retransmits
-        # them; accepted rows behave exactly like the faultless codec path.
-        res_rows = row_ops.gather(codec_state, idx)          # (M_s, K)
-        eff = grads + res_rows
-        wire = encode(up_cfg, eff)
-        decoded = decode(up_cfg, wire, kdim)
-        sums = row_checksums(wire)
-        received = flip_row_bits(wire, corrupt)
-        intact = verify_rows(received, sums)                 # (M_s,) bool
-        keep = intact[:, None]
-        grads_hat = jnp.where(keep, decoded, 0.0)
-        if is_stateful(up_cfg):
-            new_res = jnp.where(keep, eff - decoded, eff)
+        with jax.named_scope("fl_grad"):
+            parts.append(ops.fcf_item_gradients(
+                q_star, p_i, x_blocks[i], alpha=cf_cfg.alpha, l2=0.0))
+    with jax.named_scope("fl_grad"):
+        parts = jnp.stack(parts)                             # (C, M_s, K)
+        if shard is not None:
+            # ordered psum: all-gather the per-device partials and reduce
+            # in fixed block order — bit-stable against the single-device
+            # scan over the same blocks (a raw lax.psum orders by topology)
+            parts = jax.lax.all_gather(parts, shard.axis, axis=0,
+                                       tiled=True)
+        parts = optimization_barrier(parts)
+        grads = (jnp.sum(parts, axis=0)
+                 + 2.0 * cf_cfg.l2 * num_users * q_star)     # (M_s, K)
+    with jax.named_scope("fl_commit"):
+        # uplink encode (+ error feedback for stateful codecs): the server
+        # only ever sees the decoded wire image of the aggregated gradient
+        codec_state = state.codec
+        intact = None
+        if corrupt is not None:
+            # payload integrity path: checksum the encoded wire, flip the
+            # scheduled rows' bits in transit, reject rows whose received
+            # image no longer matches. Rejected rows keep their full
+            # effective gradient in the residual so the next round's encode
+            # retransmits them; accepted rows behave exactly like the
+            # faultless codec path.
+            res_rows = row_ops.gather(codec_state, idx)      # (M_s, K)
+            eff = grads + res_rows
+            wire = encode(up_cfg, eff)
+            decoded = decode(up_cfg, wire, kdim)
+            sums = row_checksums(wire)
+            received = flip_row_bits(wire, corrupt)
+            intact = verify_rows(received, sums)             # (M_s,) bool
+            keep = intact[:, None]
+            grads_hat = jnp.where(keep, decoded, 0.0)
+            if is_stateful(up_cfg):
+                new_res = jnp.where(keep, eff - decoded, eff)
+            else:
+                new_res = jnp.where(keep, jnp.zeros_like(eff), eff)
+            codec_state = row_ops.scatter_set(codec_state, idx, new_res)
+        elif is_stateful(up_cfg):
+            res_rows = row_ops.gather(codec_state, idx)      # (M_s, K)
+            _, grads_hat, new_res = encode_with_residual(up_cfg, grads,
+                                                         res_rows)
+            codec_state = row_ops.scatter_set(codec_state, idx, new_res)
         else:
-            new_res = jnp.where(keep, jnp.zeros_like(eff), eff)
-        codec_state = row_ops.scatter_set(codec_state, idx, new_res)
-    elif is_stateful(up_cfg):
-        res_rows = row_ops.gather(codec_state, idx)          # (M_s, K)
-        _, grads_hat, new_res = encode_with_residual(up_cfg, grads, res_rows)
-        codec_state = row_ops.scatter_set(codec_state, idx, new_res)
-    else:
-        grads_hat = decode(up_cfg, encode(up_cfg, grads), kdim)
-    grads_hat = optimization_barrier(grads_hat)
+            grads_hat = decode(up_cfg, encode(up_cfg, grads), kdim)
+        grads_hat = optimization_barrier(grads_hat)
 
-    # line 13: sparse Adam commit on the selected rows (scatter kernels;
-    # shard-local scatters against the row-sharded tables when sharded),
-    # step-discounted by staleness under the async engine
-    q_new, opt = adam_update_rows_scattered(
-        grads_hat, idx, state.opt, state.q, config.adam, row_ops=row_ops,
-        row_weights=step_weight, row_mask=intact,
-        moment=config.moment, moment_key=moment_key)
+        # line 13: sparse Adam commit on the selected rows (scatter
+        # kernels; shard-local scatters against the row-sharded tables when
+        # sharded), step-discounted by staleness under the async engine
+        q_new, opt = adam_update_rows_scattered(
+            grads_hat, idx, state.opt, state.q, config.adam, row_ops=row_ops,
+            row_weights=step_weight, row_mask=intact,
+            moment=config.moment, moment_key=moment_key)
 
-    # lines 14-18: reward feedback + posterior update — on the decoded
-    # gradients (the only thing a codec-running server would have), delay-
-    # corrected to the pull round when the feedback arrived stale
-    feedback = grads_hat
-    if config.reward_feedback == "data_term":
-        feedback = optimization_barrier(
-            grads_hat - 2.0 * config.l2 * num_users * q_star)
-    sel, rewards = selector_observe(sel_cfg, sel, idx, feedback,
-                                    row_ops=row_ops, t_obs=t_obs,
-                                    row_mask=intact)
-    stats = None
-    if want_stats:
-        delta = row_ops.gather(q_new, idx) - row_ops.gather(state.q, idx)
-        stats = (jnp.linalg.norm(grads_hat), jnp.linalg.norm(delta))
+        # lines 14-18: reward feedback + posterior update — on the decoded
+        # gradients (the only thing a codec-running server would have),
+        # delay-corrected to the pull round when the feedback arrived stale
+        feedback = grads_hat
+        if config.reward_feedback == "data_term":
+            feedback = optimization_barrier(
+                grads_hat - 2.0 * config.l2 * num_users * q_star)
+        sel, rewards = selector_observe(sel_cfg, sel, idx, feedback,
+                                        row_ops=row_ops, t_obs=t_obs,
+                                        row_mask=intact)
+        stats = None
+        if want_stats:
+            delta = row_ops.gather(q_new, idx) - row_ops.gather(state.q, idx)
+            stats = (jnp.linalg.norm(grads_hat), jnp.linalg.norm(delta))
     return q_new, opt, sel, codec_state, rewards, num_users, stats, intact
 
 
@@ -735,31 +746,35 @@ def server_round_step_async(
         "server_round_step_async needs a state built with "
         "server_init(async_slots=...)")
     slots = sel_async.pending.t.shape[0]
-    key, k_sel = jax.random.split(state.key)
 
     # publish: fresh pull, encode, push wire + pending attribution. The
     # barrier pins the wire image's producer graph at the push — the popped
     # snapshot must decode from the same materialized bits no matter which
     # round (or which shard program) consumes it.
-    idx, inner = selector_select(sel_cfg, sel_async.inner, k_sel)
     t_now = state.t + 1
     slot_now = jax.lax.rem(t_now - 1, slots)
-    wire_now = optimization_barrier(
-        _downlink_wire(state.q, idx, down_cfg, shard))
-    ring = _ring_put(state.snapshots, slot_now, wire_now)
-    pending = pending_record(sel_async.pending, slot_now, idx, t_now)
-    bytes_down = state.bytes_down + wire_bytes(down_cfg, m_s, kdim)
+    with jax.named_scope("fl_select"):
+        key, k_sel = jax.random.split(state.key)
+        idx, inner = selector_select(sel_cfg, sel_async.inner, k_sel)
+        pending = pending_record(sel_async.pending, slot_now, idx, t_now)
+    with jax.named_scope("fl_downlink"):
+        wire_now = optimization_barrier(
+            _downlink_wire(state.q, idx, down_cfg, shard))
+        ring = _ring_put(state.snapshots, slot_now, wire_now)
+        bytes_down = state.bytes_down + wire_bytes(down_cfg, m_s, kdim)
 
-    # commit: pop the snapshot `staleness` rounds back and solve against it
-    s = jnp.asarray(staleness, jnp.int32)
-    slot_old = jax.lax.rem(t_now - 1 - s, slots)
-    idx_s, t_s = pending_lookup(pending, slot_old)
-    q_star = decode(down_cfg, _ring_get(ring, slot_old), kdim)
-    q_star = optimization_barrier(q_star)
-    step_weight = jnp.full(
-        (m_s,),
-        jnp.power(jnp.float32(config.staleness_discount),
-                  s.astype(jnp.float32)))
+        # commit: pop the snapshot `staleness` rounds back and solve
+        # against it
+        s = jnp.asarray(staleness, jnp.int32)
+        slot_old = jax.lax.rem(t_now - 1 - s, slots)
+        idx_s, t_s = pending_lookup(pending, slot_old)
+        q_star = decode(down_cfg, _ring_get(ring, slot_old), kdim)
+        q_star = optimization_barrier(q_star)
+    with jax.named_scope("fl_commit"):
+        step_weight = jnp.full(
+            (m_s,),
+            jnp.power(jnp.float32(config.staleness_discount),
+                      s.astype(jnp.float32)))
     moment_key = (jax.random.fold_in(k_sel, _MOMENT_KEY_SALT)
                   if needs_sr_key(config.moment) else None)
     has_corrupt = faults is not None and not isinstance(faults.corrupt, tuple)
@@ -771,19 +786,20 @@ def server_round_step_async(
             want_stats=telemetry,
             corrupt=faults.corrupt if has_corrupt else None,
             moment_key=moment_key)
-    per_user_bytes = wire_bytes(up_cfg, m_s, kdim)
-    if has_corrupt:
-        per_user_bytes += m_s * CHECKSUM_BYTES_PER_ROW
-    bytes_up = state.bytes_up + per_user_bytes * num_users
+    with jax.named_scope("fl_commit"):
+        per_user_bytes = wire_bytes(up_cfg, m_s, kdim)
+        if has_corrupt:
+            per_user_bytes += m_s * CHECKSUM_BYTES_PER_ROW
+        bytes_up = state.bytes_up + per_user_bytes * num_users
 
-    fault_state = state.faults
-    if faults is not None:
-        rejected = (jnp.zeros((), jnp.float32) if intact is None
-                    else jnp.sum(~intact).astype(jnp.float32))
-        fault_state = fault_state_update(
-            state.faults, faults.dropped, faults.stragglers, rejected,
-            rejected * float(wire_bytes(up_cfg, 1, kdim)
-                             + CHECKSUM_BYTES_PER_ROW))
+        fault_state = state.faults
+        if faults is not None:
+            rejected = (jnp.zeros((), jnp.float32) if intact is None
+                        else jnp.sum(~intact).astype(jnp.float32))
+            fault_state = fault_state_update(
+                state.faults, faults.dropped, faults.stragglers, rejected,
+                rejected * float(wire_bytes(up_cfg, 1, kdim)
+                                 + CHECKSUM_BYTES_PER_ROW))
 
     new_state = state._replace(
         q=q_new, opt=opt,

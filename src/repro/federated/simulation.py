@@ -80,7 +80,7 @@ from repro.obs.config import ObsConfig
 from repro.obs.telemetry import (
     make_row_emitter, telemetry_round, telemetry_state_init,
 )
-from repro.obs.trace import install_tracer, span
+from repro.obs.trace import install_tracer, recording, span
 from repro.optim.adam import AdamConfig
 from repro.optim.state_compress import (
     MomentCodecConfig, validate_config as validate_moment_config,
@@ -870,7 +870,9 @@ def run_fcf_simulation(
     python engine emits per round. Host spans (train_chunk / eval /
     publish) go to ``obs.trace_path`` when set, and ``obs.profile_dir``
     wraps the whole training loop in ``jax.profiler.trace``. Disabled or
-    absent, none of this exists in the compiled programs.
+    absent, none of this exists in the compiled programs; the spans still
+    reach any profiler session that is running, and the round's phases
+    carry their ``fl_*`` scope names in the programs' debug info.
     """
     train_j = jnp.asarray(train_x, jnp.float32)
     test_j = jnp.asarray(test_x, jnp.float32)
@@ -1086,6 +1088,11 @@ def _run_single(train_j, setup, config, record, obs, csv_path) -> SimResult:
                             kw["rf"] = round_faults_xs(
                                 setup.fault_sched, lo, hi, pad_to=pad_total)
                         state, aux = run_chunk(state, *args, **kw)
+                        if recording():
+                            # the recorded span times the chunk's work, not
+                            # its enqueue; the profiler's device trace
+                            # holds that time without a sync
+                            jax.block_until_ready(state)
                 if crash is not None:
                     raise SimulatedCrash(crash, config.checkpoint_dir)
                 if record:
@@ -1093,7 +1100,7 @@ def _run_single(train_j, setup, config, record, obs, csv_path) -> SimResult:
                 with span("eval", round=end):
                     m = _evaluate(state.q, setup.eval_train,
                                   setup.eval_test, config)
-                history.log(end, **m.as_dict())
+                    history.log(end, **m.as_dict())
                 if config.checkpoint_dir is not None:
                     save_checkpoint(config.checkpoint_dir, end, state)
                 if config.snapshot_hook is not None:
@@ -1137,7 +1144,7 @@ def _run_single(train_j, setup, config, record, obs, csv_path) -> SimResult:
                     with span("eval", round=t):
                         m = _evaluate(state.q, setup.eval_train,
                                       setup.eval_test, config)
-                    history.log(t, **m.as_dict())
+                        history.log(t, **m.as_dict())
                     if config.checkpoint_dir is not None:
                         save_checkpoint(config.checkpoint_dir, t, state)
                     if config.snapshot_hook is not None:

@@ -13,7 +13,8 @@ Modules:
     computed inside the fused round step, the regret-tracking scan carry,
     and the JSONL round-event schema.
   * :mod:`repro.obs.sinks`     — pluggable event sinks (jsonl/csv/memory).
-  * :mod:`repro.obs.trace`     — host-side nested span tracing (JSONL).
+  * :mod:`repro.obs.trace`     — host spans to the profiler trace and a
+    JSONL log.
   * :mod:`repro.obs.hist`      — HDR-style latency histograms shared by
     the serving engine, the serving bench and the examples.
   * :mod:`repro.obs.prom`      — Prometheus text exposition + parser.
@@ -27,7 +28,7 @@ from repro.obs.telemetry import (
     TELEMETRY_FIELDS, RoundTelemetry, TelemetryState, rows_to_events,
     telemetry_round, telemetry_state_init, validate_round_event,
 )
-from repro.obs.trace import NullTracer, Tracer, install_tracer, span, traced
+from repro.obs.trace import NullTracer, Tracer, install_tracer, span
 
 __all__ = [
     "ObsConfig", "LatencyHistogram",
@@ -35,5 +36,5 @@ __all__ = [
     "TELEMETRY_FIELDS", "RoundTelemetry", "TelemetryState",
     "telemetry_state_init", "telemetry_round", "rows_to_events",
     "validate_round_event",
-    "Tracer", "NullTracer", "install_tracer", "span", "traced",
+    "Tracer", "NullTracer", "install_tracer", "span",
 ]

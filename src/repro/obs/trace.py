@@ -1,19 +1,28 @@
-"""Host-side span tracing: nested spans to a JSONL event log.
+"""Host-side span tracing: nested spans to the profiler and a JSONL log.
 
-``span("name", attr=...)`` wraps the host-side phases of a run — snapshot
-publish, ring-chunk execution, eval, checkpoint save/restore, serving
-batch assembly — and records one event per span with monotonic
-timestamps, duration, nesting depth and parent name. The module-level
-:func:`span` dispatches to the *installed* tracer; the default is a
-:class:`NullTracer` whose ``span`` returns a shared reusable no-op
-context manager, so instrumented call sites cost one attribute load and
-a no-op ``__enter__``/``__exit__`` when tracing is off — nothing is
-formatted, allocated per-call, or written.
+``span("name", attr=...)`` wraps the host-side phases of a run — training
+chunk, eval, snapshot publish (``publish.encode``, ``publish.install``),
+checkpoint save/restore, serving batch — and does two things:
+
+  * it always opens a ``jax.profiler.TraceAnnotation`` of the same name,
+    so whenever a profiler session is running (``jax.profiler.trace``,
+    ``ObsConfig.profile_dir``) the span lands on the ``/host:CPU`` plane
+    of the ``.xplane.pb``, on the device ops' clock. With no profiler
+    running that is one annotation enter and exit, about a microsecond;
+  * with a :class:`Tracer` installed it also records one event per span
+    with monotonic timestamps, duration, nesting depth and parent name.
+
+The module-level :func:`span` dispatches to the *installed* tracer; the
+default :class:`NullTracer` hands back the bare annotation, so nothing is
+formatted or written. :func:`recording` tells the loop whether a tracer
+records durations, so a span that should time device work can sync
+before it closes (``train_chunk``) only when someone reads the number.
 
 Span events (one JSON object per line)::
 
-    {"type": "span", "name": "train.chunk", "ts": 12.031, "dur": 0.482,
-     "depth": 0, "parent": null, "attrs": {"t0": 0, "t1": 25}}
+    {"type": "span", "name": "train_chunk", "ts": 12.031, "dur": 0.482,
+     "depth": 0, "parent": null,
+     "attrs": {"start": 0, "end": 25, "backend": "scan"}}
 
 ``ts`` is seconds on the monotonic clock relative to tracer creation.
 Nesting is tracked per thread, so concurrent serving threads produce
@@ -21,57 +30,50 @@ well-formed (if interleaved) span streams.
 """
 from __future__ import annotations
 
-import functools
 import json
 import os
 import threading
 import time
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 SPAN_REQUIRED_KEYS = ("type", "name", "ts", "dur", "depth")
 
 
-class _NullSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc) -> bool:
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
 class NullTracer:
-    """The no-op default: ``span`` hands back one shared null context."""
+    """The default: ``span`` is the profiler annotation alone; no event is
+    recorded."""
 
     def span(self, name: str, **attrs) -> Any:
-        return _NULL_SPAN
+        return TraceAnnotation(name)
 
     def close(self) -> None:
         pass
 
 
 class _Span:
-    __slots__ = ("tracer", "name", "attrs", "t0", "depth", "parent")
+    __slots__ = ("tracer", "name", "attrs", "t0", "depth", "parent",
+                 "annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
         self.tracer = tracer
         self.name = name
         self.attrs = attrs
+        self.annotation = TraceAnnotation(name)
 
     def __enter__(self):
         stack = self.tracer._stack()
         self.depth = len(stack)
         self.parent = stack[-1] if stack else None
         stack.append(self.name)
+        self.annotation.__enter__()
         self.t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc) -> bool:
         dur = time.monotonic() - self.t0
+        self.annotation.__exit__(None, None, None)
         self.tracer._stack().pop()
         event = {
             "type": "span",
@@ -144,24 +146,14 @@ def active_tracer() -> Any:
 
 
 def span(name: str, **attrs) -> Any:
-    """A span context on the installed tracer (no-op unless installed)."""
+    """A span context on the installed tracer: a profiler annotation, and a
+    recorded event when a :class:`Tracer` is installed."""
     return _active.span(name, **attrs)
 
 
-def traced(name: Optional[str] = None):
-    """Decorator form: wrap a function call in a span."""
-
-    def deco(fn):
-        span_name = name or fn.__qualname__
-
-        @functools.wraps(fn)
-        def wrapper(*args, **kwargs):
-            with _active.span(span_name):
-                return fn(*args, **kwargs)
-
-        return wrapper
-
-    return deco
+def recording() -> bool:
+    """Whether the installed tracer records span durations."""
+    return not isinstance(_active, NullTracer)
 
 
 def validate_span_event(event: Any) -> List[str]:

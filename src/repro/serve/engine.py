@@ -11,7 +11,7 @@ pure model:
     all-zero factor vectors whose results are sliced off before returning.
   * SNAPSHOT PUBLISH/SWAP — training publishes encoded payload rows
     (the async ring's :class:`repro.cf.server.EncodedSnapshot` entries);
-    ``publish_snapshot`` patches them into the wire-resident model and
+    ``publisher()`` patches them into the wire-resident model and
     atomically swaps the result in. The swap is a single reference
     assignment under a lock with a monotonically bumped version;
     in-flight requests keep the model value they grabbed at entry (JAX
@@ -139,10 +139,6 @@ class ServingEngine:
         """Patch encoded payload rows into the live model and swap."""
         return self.swap(self.model.install_rows(indices, rows_wire))
 
-    def publish_snapshot(self, snapshot) -> ServingModel:
-        """Install an async-ring :class:`EncodedSnapshot` (no fp32 decode)."""
-        return self.swap(self.model.install_snapshot(snapshot))
-
     def publisher(self):
         """A ``(round, ServerState) -> None`` hook for ``FLSimConfig
         .snapshot_hook``: publishes each eval-boundary state into this
@@ -168,16 +164,20 @@ class ServingEngine:
                 try:
                     with span("publish_snapshot", round=round_,
                               attempt=attempt):
-                        if state.snapshots != ():
-                            from repro.cf.server import latest_snapshot
-                            snap = latest_snapshot(state)
-                            self.publish_snapshot(snap)
-                            age = round_ - int(snap.t) if self._obs_on else 0
-                        else:
-                            cur = self.model
-                            self.swap(ServingModel.from_dense(
-                                cur.cfg, state.q, version=cur.version + 1))
-                            age = 0     # sync states publish their live table
+                        cur = self.model
+                        with span("publish.encode"):
+                            if state.snapshots != ():
+                                from repro.cf.server import latest_snapshot
+                                snap = latest_snapshot(state)
+                                model = cur.install_snapshot(snap)
+                                age = (round_ - int(snap.t) if self._obs_on
+                                       else 0)
+                            else:
+                                model = ServingModel.from_dense(
+                                    cur.cfg, state.q, version=cur.version + 1)
+                                age = 0  # sync states publish their live table
+                        with span("publish.install"):
+                            self.swap(model)
                     with self._lock:
                         self._snapshot_age = age
                     return

@@ -36,7 +36,9 @@ from repro.obs import (  # noqa: E402
     install_tracer, rows_to_events, span, validate_round_event,
 )
 from repro.obs.prom import parse, validate_text  # noqa: E402
-from repro.obs.trace import NullTracer, active_tracer, validate_span_event  # noqa: E402
+from repro.obs.trace import (  # noqa: E402
+    NullTracer, active_tracer, recording, validate_span_event,
+)
 
 BACKENDS = ("scan", "python", "async")
 
@@ -296,12 +298,128 @@ def test_tracer_nested_spans_schema_and_restore(tmp_path):
 
 
 def test_null_tracer_span_is_shared_noop():
-    """The default tracer hands back ONE reusable null context — the cost
-    of an instrumented call site with tracing off is near zero."""
+    """The default tracer hands back the bare profiler annotation of the
+    span's name — one enter and exit with no profiler running — and
+    records nothing."""
+    from jax.profiler import TraceAnnotation
+
     nt = NullTracer()
-    assert nt.span("a") is nt.span("b", attr=1)
+    assert type(nt.span("a")) is type(nt.span("b", attr=1)) \
+        is TraceAnnotation
     with nt.span("a"):
         pass                                             # no-op, no error
+    assert active_tracer() is not nt and not recording()
+
+
+PROGRAM_SPANS = ("train_chunk", "eval", "publish", "publish_snapshot",
+                 "publish.encode", "publish.install")
+ROUND_SCOPES = ("fl_select", "fl_downlink", "fl_gather", "fl_solve",
+                "fl_grad", "fl_commit")
+
+
+def _host_span_names(profile_dir):
+    from jax.profiler import ProfileData
+
+    (path,) = pathlib.Path(profile_dir).glob("**/*.xplane.pb")
+    host = ProfileData.from_file(str(path)).find_plane_with_name("/host:CPU")
+    return [e.name for line in host.lines for e in line.events]
+
+
+@pytest.mark.parametrize("jsonl", [False, True], ids=["profiler", "jsonl"])
+def test_spans_land_on_the_profiler_trace(tmp_path, jsonl):
+    """Every program span is a profiler annotation: a ``jax.profiler``
+    session around the loop finds them on the host plane, with or without
+    a JSONL tracer installed."""
+    import jax
+    from repro.compress import CodecConfig
+    from repro.serve import ServingEngine, ServingModel
+
+    train, test = _mini_data()
+    engine = ServingEngine(ServingModel.from_dense(
+        CodecConfig(name="int8"), np.zeros((train.shape[1], 25), np.float32)))
+    cfg = _cfg("scan", snapshot_hook=engine.publisher())
+    if jsonl:
+        cfg = replace(cfg, obs=ObsConfig(
+            enabled=True, trace_path=str(tmp_path / "spans.jsonl")))
+    with jax.profiler.trace(str(tmp_path / "prof")):
+        run_fcf_simulation(train, test, cfg)
+    names = _host_span_names(tmp_path / "prof")
+    chunks = cfg.rounds // cfg.eval_every
+    for name in PROGRAM_SPANS:
+        assert names.count(name) == chunks, (name, names.count(name))
+    if jsonl:
+        cfg.obs.close()
+        events = [json.loads(line) for line in
+                  (tmp_path / "spans.jsonl").read_text().splitlines()]
+        assert sorted({e["name"] for e in events}) == sorted(PROGRAM_SPANS)
+        nested = {e["name"]: e["parent"] for e in events}
+        assert nested["publish.encode"] == "publish_snapshot"
+        assert nested["publish.install"] == "publish_snapshot"
+
+
+@pytest.mark.parametrize("jsonl", [False, True], ids=["profiler", "jsonl"])
+def test_train_chunk_span_syncs_only_when_recorded(monkeypatch, jsonl):
+    """Under a JSONL tracer the ``train_chunk`` span closes on the chunk's
+    finished state, so its duration is the chunk's work; with only the
+    profiler it adds no sync."""
+    import jax
+    from repro.federated import simulation
+
+    synced = []
+    real = jax.block_until_ready
+
+    def spy(x):
+        synced.append(active_tracer()._stack()[-1]
+                      if recording() else None)
+        return real(x)
+
+    monkeypatch.setattr(simulation.jax, "block_until_ready", spy)
+    train, test = _mini_data()
+    tracer = Tracer() if jsonl else None
+    prev = install_tracer(tracer)
+    try:
+        run_fcf_simulation(train, test, _cfg("scan"))
+    finally:
+        install_tracer(prev)
+    chunks = 6 // 3
+    assert synced == (["train_chunk"] * chunks if jsonl else [])
+    if jsonl:
+        assert [e["name"] for e in tracer.events].count("train_chunk") \
+            == chunks
+
+
+@pytest.mark.parametrize("backend", ["scan", "async"])
+def test_round_phases_are_named_in_the_compiled_chunk(backend):
+    """Each phase of the compiled round carries its ``fl_*`` scope in the
+    chunk program's debug info (the name stack the device trace shows)."""
+    import jax
+    import jax.numpy as jnp
+    from repro.federated.simulation import (
+        _build, _make_async_round_fn, _make_round_fn,
+    )
+
+    train, test = _mini_data()
+    cfg = _cfg(backend)
+    train_j = jnp.asarray(train)
+    setup = _build(train_j, jnp.asarray(test), cfg)
+    cohorts = jnp.asarray(setup.cohorts[:3])
+    if backend == "async":
+        round_fn = _make_async_round_fn(train_j, setup, cfg.blocks_per_commit)
+        xs = (cohorts, jnp.asarray(setup.staleness[:3], jnp.int32))
+    else:
+        round_fn = _make_round_fn(train_j, setup)
+        xs = cohorts
+
+    def scan_chunk(st, xs):
+        def body(s, x):
+            args = x if isinstance(x, tuple) else (x,)
+            return round_fn(s, *args)[0], None
+        return jax.lax.scan(body, st, xs)
+
+    text = jax.jit(scan_chunk).lower(setup.state0, xs).as_text(
+        debug_info=True)
+    missing = [name for name in ROUND_SCOPES if name not in text]
+    assert not missing, missing
 
 
 # --------------------------------------------------------------------- #
