@@ -394,6 +394,19 @@ def _staleness_schedule(config: FLSimConfig) -> np.ndarray:
     return np.minimum(s, np.arange(rounds)).astype(np.int32)
 
 
+def _cohort_block(train: jax.Array, ids: jax.Array,
+                  idx: jax.Array) -> jax.Array:
+    """``train[ids][:, idx]``: the cohort's (len(ids), len(idx)) block.
+
+    Gathered in two stages of contiguous slices: the cohort's user rows
+    whole, (B, M), then the payload columns as rows of that slab's
+    transpose, (M_s, B). The one-step ``train[ids[:, None], idx[None, :]]``
+    lowers to B x M_s single-element slices, which cost per element on the
+    TPU. Both are exact, so the block is bit-equal either way.
+    """
+    return train[ids].T[idx].T
+
+
 def _blocked_cohort_x(train_j: jax.Array, ids: jax.Array, shards: int,
                       num_users: int, survivors: Optional[jax.Array] = None):
     """Lazy blocked cohort slice for the round step.
@@ -414,15 +427,40 @@ def _blocked_cohort_x(train_j: jax.Array, ids: jax.Array, shards: int,
     b = total // shards
 
     def cohort_x(idx):
-        # one fused (user-row x item-column) gather once the payload subset
-        # is known, instead of a (B, M) copy per round
-        x = train_j[ids[:, None], idx[None, :]]              # (total, M_s)
+        x = _cohort_block(train_j, ids, idx)                 # (total, M_s)
         if num_users < total:
             mask = (jnp.arange(total) < num_users).astype(x.dtype)
             x = x * mask[:, None]
         if survivors is not None:
             x = x * survivors.astype(x.dtype)[:, None]
         return x.reshape(c_local, b, idx.shape[0])
+
+    return cohort_x
+
+
+def _local_cohort_x(ids: jax.Array, didx: jax.Array, train_rep: jax.Array,
+                    shards: int, num_users: int,
+                    survivors: Optional[jax.Array] = None):
+    """The shard engine's per-device cohort slice, ``idx -> (1, b, M_s)``.
+
+    ``ids`` is device ``didx``'s block of the padded cohort, gathered from
+    the replicated ``train_rep``; rows past ``num_users`` and, given
+    ``survivors`` (the full replicated (shards*b,) padded keep vector), the
+    dropped users' rows are zeroed exactly as :func:`_blocked_cohort_x`
+    zeroes them on one device.
+    """
+    b = ids.shape[0]
+    padded = shards * b != num_users
+
+    def cohort_x(idx):
+        x = _cohort_block(train_rep, ids, idx)               # (b, M_s)
+        if padded:
+            pos = didx * b + jnp.arange(b)
+            x = x * (pos < num_users).astype(x.dtype)[:, None]
+        if survivors is not None:
+            local = jax.lax.dynamic_slice_in_dim(survivors, didx * b, b)
+            x = x * local.astype(x.dtype)[:, None]
+        return x[None]                                       # (1, b, M_s)
 
     return cohort_x
 
@@ -561,7 +599,6 @@ def make_sharded_round_runner(train_j: jax.Array, setup: _SimSetup,
     b = -(-b_total // d)                  # users per device block
     shard_ctx = ShardContext(axis="data", num_shards=d, rows_per_shard=m // d)
     sel_cfg, srv_cfg, cf_cfg = setup.sel_cfg, setup.srv_cfg, setup.cf_cfg
-    padded = d * b != b_total
 
     state_specs = fcf_state_pspecs(setup.state0)
     state0 = jax.device_put(setup.state0, to_shardings(mesh, state_specs))
@@ -569,21 +606,6 @@ def make_sharded_round_runner(train_j: jax.Array, setup: _SimSetup,
     aux_specs = RoundAux(indices=P(), rewards=P()) if record else None
     telemetry = obs is not None
     fault_on = config.faults is not None and config.faults.enabled
-
-    def _local_cohort_x(ids, didx, train_rep, survivors=None):
-        # ``survivors`` is the full replicated (d*b,) padded keep vector;
-        # each device slices out its own block so the zeroing matches the
-        # single-device blocked closure exactly
-        def cohort_x(idx):
-            x = train_rep[ids[:, None], idx[None, :]]        # (b, M_s)
-            if padded:
-                pos = didx * b + jnp.arange(b)
-                x = x * (pos < b_total).astype(x.dtype)[:, None]
-            if survivors is not None:
-                local = jax.lax.dynamic_slice_in_dim(survivors, didx * b, b)
-                x = x * local.astype(x.dtype)[:, None]
-            return x[None]                                   # (1, b, M_s)
-        return cohort_x
 
     if telemetry:
         # telemetry variants: the replicated TelemetryState rides the scan
@@ -602,7 +624,7 @@ def make_sharded_round_runner(train_j: jax.Array, setup: _SimSetup,
                     cohort_l, s_t = xs
                     cohort_x = _local_cohort_x(
                         cohort_l.reshape(-1), jax.lax.axis_index("data"),
-                        train_rep)
+                        train_rep, d, b_total)
                     st, aux = server_round_step_async(
                         st, cohort_x, s_t, sel_cfg=sel_cfg, config=srv_cfg,
                         cf_cfg=cf_cfg, codec_cfg=setup.codec_cfg,
@@ -628,7 +650,7 @@ def make_sharded_round_runner(train_j: jax.Array, setup: _SimSetup,
                     st, ts = carry
                     cohort_x = _local_cohort_x(
                         cohort_l.reshape(-1), jax.lax.axis_index("data"),
-                        train_rep)
+                        train_rep, d, b_total)
                     st, aux = server_round_step(
                         st, cohort_x, sel_cfg=sel_cfg, config=srv_cfg,
                         cf_cfg=cf_cfg, codec_cfg=setup.codec_cfg,
@@ -679,7 +701,7 @@ def make_sharded_round_runner(train_j: jax.Array, setup: _SimSetup,
                 cohort_l, s_t, rf_t = xs
                 cohort_x = _local_cohort_x(
                     cohort_l.reshape(-1), jax.lax.axis_index("data"),
-                    train_rep, survivors=rf_t.survivors)
+                    train_rep, d, b_total, survivors=rf_t.survivors)
                 n_eff = jnp.sum(rf_t.survivors)
                 st, aux = server_round_step_async(
                     st, cohort_x, s_t, sel_cfg=sel_cfg, config=srv_cfg,
@@ -699,7 +721,7 @@ def make_sharded_round_runner(train_j: jax.Array, setup: _SimSetup,
                 cohort_l, rf_t = xs
                 cohort_x = _local_cohort_x(
                     cohort_l.reshape(-1), jax.lax.axis_index("data"),
-                    train_rep, survivors=rf_t.survivors)
+                    train_rep, d, b_total, survivors=rf_t.survivors)
                 n_eff = jnp.sum(rf_t.survivors)
                 st, aux = server_round_step(
                     st, cohort_x, sel_cfg=sel_cfg, config=srv_cfg,
@@ -720,7 +742,7 @@ def make_sharded_round_runner(train_j: jax.Array, setup: _SimSetup,
                 cohort_l, s_t = xs
                 cohort_x = _local_cohort_x(
                     cohort_l.reshape(-1), jax.lax.axis_index("data"),
-                    train_rep)
+                    train_rep, d, b_total)
                 st, aux = server_round_step_async(
                     st, cohort_x, s_t, sel_cfg=sel_cfg, config=srv_cfg,
                     cf_cfg=cf_cfg, codec_cfg=setup.codec_cfg,
@@ -739,7 +761,7 @@ def make_sharded_round_runner(train_j: jax.Array, setup: _SimSetup,
             def body(st, cohort_l):
                 cohort_x = _local_cohort_x(
                     cohort_l.reshape(-1), jax.lax.axis_index("data"),
-                    train_rep)
+                    train_rep, d, b_total)
                 st, aux = server_round_step(
                     st, cohort_x, sel_cfg=sel_cfg, config=srv_cfg,
                     cf_cfg=cf_cfg, codec_cfg=setup.codec_cfg,
@@ -1233,7 +1255,7 @@ def run_seed_sweep(
     def scan_chunk(st, ch, train_j):
         def body(s, cohort):
             def cohort_x(idx):
-                return train_j[cohort[:, None], idx[None, :]]
+                return _cohort_block(train_j, cohort, idx)
             s, aux = server_round_step(
                 s, cohort_x, sel_cfg=sel_cfg, config=srv_cfg, cf_cfg=cf_cfg,
                 codec_cfg=codec_cfg)

@@ -4,8 +4,9 @@ No chip is needed: the TPU compiler is installed, and it compiles for a
 topology that is described rather than attached. It refuses what Mosaic
 would refuse on the chip — blocks not aligned to the (8, 128) tiling,
 scoring tiles that overflow VMEM, shape casts it cannot lay out — none of
-which interpret mode sees. Each test asserts that the compiled program
-holds the kernel (a ``tpu_custom_call``).
+which interpret mode sees. Each kernel test asserts that the compiled
+program holds the kernel (a ``tpu_custom_call``); the cohort gather's test
+asserts that the compiler kept its gathers of whole rows.
 
 Widths: the paper's lastfm (Table 2: M=17,632 items, K=25 factors,
 Theta=100, keep 0.1 -> M_s=1,763 payload rows), K=16, Theta=500 (MIND),
@@ -15,12 +16,14 @@ block derived for each batch. Kernel modules are called directly:
 CPU here.
 """
 import inspect
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.federated.simulation import _blocked_cohort_x
 from repro.kernels import fcf_grad as fcf
 from repro.kernels import moment_quant as mq
 from repro.kernels import ops
@@ -120,6 +123,30 @@ def test_fcf_grad_compiles_for_tpu(arg, theta):
     k = 25
     _assert_kernel(fcf.fcf_grad, arg((M_S, k)), arg((theta, k)),
                    arg((theta, M_S)), alpha=4.0, l2=0.0, block_m=256)
+
+
+# (users, items, Theta, M_s) of the benchmark's two cells: Table 2 Last.FM
+# and MIND at keep 0.1
+COHORT_SHAPES = {"lastfm": (1_892, M, 100, M_S),
+                 "mind": (16_026, 6_923, 500, 692)}
+
+
+@pytest.mark.parametrize("data", sorted(COHORT_SHAPES))
+def test_cohort_gather_compiles_to_slice_gathers_for_tpu(arg, data):
+    """The TPU compiler keeps the cohort block's two stages as gathers of
+    whole rows (the interaction matrix's, then the slab transpose's): no
+    gather of single elements, which the TPU pays for one by one."""
+    users, items, theta, m_s = COHORT_SHAPES[data]
+
+    def block(train, ids, idx):
+        return _blocked_cohort_x(train, ids, 1, theta)(idx)
+
+    text = jax.jit(block).lower(arg((users, items)), arg((theta,), jnp.int32),
+                                arg((m_s,), jnp.int32)).compile().as_text()
+    sizes = [tuple(int(v) for v in g.split(","))
+             for g in re.findall(r" gather\(.*?slice_sizes=\{([0-9,]+)\}",
+                                 text)]
+    assert sorted(sizes) == [(1, theta), (1, items)], sizes
 
 
 def _score_args(arg, codec, m, k):
