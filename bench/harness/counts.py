@@ -64,12 +64,6 @@ def round_ops(num_items: int, m_s: int, theta: int, k: int,
     return ops
 
 
-def cohort_gather_bytes(theta: int, m_s: int) -> float:
-    """The cohort's (Theta, M_s) float32 block of the interaction matrix,
-    read once and written once: what the solve and the gradient read."""
-    return 2.0 * F32 * theta * m_s
-
-
 # bytes each payload row kernel moves per row of K values, by the name the
 # compiled program gives it: what it reads plus what it writes
 _ROW_BYTES_PER_ROW = {

@@ -108,16 +108,39 @@ def _select(cfg: RefRound, s: dict, k_sel: jax.Array, dtype) -> jax.Array:
     return jnp.sort(idx).astype(jnp.int32)
 
 
-def round_step(cfg: RefRound, train: jax.Array, s: dict, cohort: jax.Array,
-               dtype, fault: Optional[str]) -> dict:
-    """One FCF round (Alg. 1 lines 8-18) against the dense train matrix."""
+def cohort_block(train, cohort: jax.Array, idx: jax.Array, num_items: int,
+                 cap: Optional[int], dtype) -> jax.Array:
+    """The cohort's (B, M_s) block of the interaction matrix: from the dense
+    matrix, or from the lists ``(indptr, indices)``, whose B users hold at
+    most ``cap`` ids together, by scattering ones into (B, M) and taking the
+    selected columns."""
+    if cap is None:
+        return train[cohort][:, idx].astype(dtype)
+    indptr, indices = train
+    start = indptr[cohort]
+    count = indptr[cohort + 1] - start
+    end = jnp.cumsum(count)
+    j = jnp.arange(cap, dtype=jnp.int32)
+    row = jnp.minimum(jnp.searchsorted(end, j, side="right"),
+                      cohort.shape[0] - 1)
+    used = j < end[-1]
+    at = jnp.where(used, start[row] + j - (end[row] - count[row]), 0)
+    item = jnp.where(used, indices[at], num_items)          # dropped
+    x = jnp.zeros((cohort.shape[0], num_items), dtype)
+    return x.at[row, item].set(1, mode="drop")[:, idx]
+
+
+def round_step(cfg: RefRound, train, s: dict, cohort: jax.Array, dtype,
+               fault: Optional[str], cap: Optional[int] = None) -> dict:
+    """One FCF round (Alg. 1 lines 8-18) against the train interactions
+    (see :func:`cohort_block`)."""
     if fault == "unchanged":
         return s
     key, k_sel = jax.random.split(s["key"])
     t = s["t"] + 1
     idx = _select(cfg, s, k_sel, dtype)
     q_star = wire(s["q"][idx])                                  # downlink
-    x = train[cohort][:, idx].astype(dtype)                     # (B, M_s)
+    x = cohort_block(train, cohort, idx, cfg.num_items, cap, dtype)
     n_users = cohort.shape[0]
     data_scale = 1.0
     if fault == "half_cohort":
@@ -168,27 +191,39 @@ def round_step(cfg: RefRound, train: jax.Array, s: dict, cohort: jax.Array,
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "dtype", "fault"))
-def _scan(cfg: RefRound, train: jax.Array, s0: dict, ch: jax.Array, dtype,
-          fault: Optional[str]) -> dict:
+@functools.partial(jax.jit, static_argnames=("cfg", "dtype", "fault", "cap"))
+def _scan(cfg: RefRound, train, s0: dict, ch: jax.Array, dtype,
+          fault: Optional[str], cap: Optional[int]) -> dict:
     def body(s, cohort):
-        return round_step(cfg, train, s, cohort, dtype, fault), None
+        return round_step(cfg, train, s, cohort, dtype, fault, cap), None
 
     with jax.default_matmul_precision("highest"):
         return jax.lax.scan(body, s0, ch)[0]
 
 
-def run_training(cfg: RefRound, train: jax.Array, seed: int, rounds: int,
+def run_training(cfg: RefRound, train, seed: int, rounds: int,
                  dtype=jnp.float32, fault: Optional[str] = None
                  ) -> Dict[str, np.ndarray]:
     """The reference's state after ``rounds`` rounds from the seed, with its
-    initial model under ``q0``."""
+    initial model under ``q0``. ``train`` is the dense (users, items) matrix
+    or the CSR triple ``(indptr, indices, (users, items))``."""
     if fault not in FAULTS:
         raise ValueError(f"fault must be one of {FAULTS}, got {fault!r}")
-    ch = jnp.asarray(cohorts(seed, rounds, train.shape[0], cfg.theta))
+    cap = None
+    if isinstance(train, tuple):
+        indptr, indices, (num_users, _) = train
+        if indptr[-1] >= 2 ** 31:
+            raise ValueError("the lists hold more ids than int32 indexes")
+        count = np.sort(np.diff(indptr))
+        cap = max(1, int(count[len(count) - min(cfg.theta, num_users):]
+                         .sum()))
+        train = (jnp.asarray(indptr.astype(np.int32)), jnp.asarray(indices))
+    else:
+        num_users = train.shape[0]
+    ch = jnp.asarray(cohorts(seed, rounds, num_users, cfg.theta))
     s0 = init_state(cfg, seed, dtype)
     q0 = np.asarray(s0["q"], np.float32)
-    final = _scan(cfg, train, s0, ch, jnp.dtype(dtype), fault)
+    final = _scan(cfg, train, s0, ch, jnp.dtype(dtype), fault, cap)
     out = {k: np.asarray(v.astype(jnp.float32) if v.dtype == dtype else v)
            for k, v in final.items() if k != "key"}
     out["q0"] = q0

@@ -155,7 +155,7 @@ def engine_for(table, wire: str, mix: dict):
 def build(jax, cell, seed: int):
     """The engine over a seeded int8 table, the users' factors from the
     program's own solve, and the seen-item rows (the whole table, host
-    uint8)."""
+    uint8). Dense configurations only."""
     import jax.numpy as jnp
 
     from repro.cf.local import solve_user_factors
@@ -163,6 +163,11 @@ def build(jax, cell, seed: int):
     from bench.harness import data
 
     cfg, mix = cell.config, cell.traffic
+    if data.layout(cfg["data"]) != "dense":
+        raise ValueError(
+            f"the serving driver takes a dense configuration only; "
+            f"{cfg['name']} states layout {data.layout(cfg['data'])!r}, "
+            f"whose seen-item rows it cannot hold whole")
     m, k = cfg["data"]["num_items"], cfg["num_factors"]
     train, _ = data.dataset(cfg["data"], CACHE_DIR / "data")
     scale = float(mix["table_scale"])

@@ -140,12 +140,18 @@ def sim_config(cfg: dict, mix: dict, seed: int, hook):
 
 
 def device_data(jax, cfg: dict):
-    """The configuration's train and test matrices as float32 on the chip."""
+    """The configuration's train and test interactions as the program takes
+    them: (users, items) float32 matrices on the chip, or, for a ``lists``
+    layout, a CSR triple ``(indptr, indices, (users, items))`` of host
+    NumPy arrays per split, which the program lays out on the chip in its
+    own set-up (``bench/README.md``)."""
     import jax.numpy as jnp
 
     from bench.harness import data
 
     train, test = data.dataset(cfg["data"], CACHE_DIR / "data")
+    if data.layout(cfg["data"]) == "lists":
+        return train, test
     to_f32 = jax.jit(lambda a: a.astype(jnp.float32))
     return to_f32(jnp.asarray(train)), to_f32(jnp.asarray(test))
 
@@ -211,19 +217,26 @@ def precision_at_10(engine, state, train_j, test_j, users: int = 512,
     """P@10 of the model the engine serves at the window's end, over a fixed
     sample of users: the share of each user's top 10 unseen items that are
     in the user's held-out test items (a count for the record, not a
-    metric)."""
+    metric). The sampled users' rows come from the matrices, or are built
+    from the lists."""
     import jax.numpy as jnp
     import numpy as np
 
     from repro.cf.local import solve_user_factors
 
+    from bench.harness import data
+
+    n = train_j.shape[0]
     rng = np.random.default_rng(seed)
-    ids = np.sort(rng.choice(train_j.shape[0], min(users, train_j.shape[0]),
-                             replace=False))
-    seen = train_j[jnp.asarray(ids)]
+    ids = np.sort(rng.choice(n, min(users, n), replace=False))
+    if isinstance(train_j, data.CSR):
+        seen = jnp.asarray(data.rows(train_j, ids))
+        test = data.rows(test_j, ids)
+    else:
+        seen = train_j[jnp.asarray(ids)]
+        test = np.asarray(test_j[jnp.asarray(ids)])
     p = solve_user_factors(state.q, seen)
     _, top = engine.recommend(p, top_n=10, train_mask=seen)
-    test = np.asarray(test_j[jnp.asarray(ids)])
     hits = np.take_along_axis(test, np.asarray(top), axis=1)
     return float(hits.sum() / hits.size)
 

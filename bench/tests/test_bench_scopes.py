@@ -153,7 +153,7 @@ def test_publish_reader_counts_the_launches_inside_publish(chip_scopes,
 
 
 def test_gather_scope_agrees_with_the_shape_heuristic(chip_scopes):
-    # cohort_gather_roofline's ops: every leaf outside the kernels whose
+    # the block by its shape alone: every leaf outside the kernels whose
     # result holds Theta x M_s values (100 x 1763 on lastfm)
     block = [o for o in chip_scopes.ops
              if "tpu_custom_call" not in o.op.text

@@ -129,30 +129,3 @@ def test_unknown_device_kind_is_an_error():
 
     with pytest.raises(ValueError):
         peaks_for("cpu")
-
-
-def _gather_ctx(summary, rounds):
-    cell = SimpleNamespace(config={"theta": 4, "num_factors": 2})
-    return SimpleNamespace(cell=cell, device_kind="TPU v5 lite",
-                           summary=summary, num_select=6,
-                           traced_rounds=rounds)
-
-
-def test_cohort_gather_share_counts_the_block_ops_of_every_round():
-    # per round: index arithmetic and the gather, each of 4 x 6 = 24 values;
-    # the fcf_grad kernel's (6, 2) output and a reshape of another size
-    # are not the block
-    rnd = [("%broadcast_or_fusion.1 = s32[4,6,1] fusion()", 0, 2),
-           ("%fusion.2 = f32[24] fusion()", 2, 6),
-           (f"%fcf_grad.3 = f32[6,2] custom-call() {CUSTOM}", 8, 4),
-           ("%reshape.4 = f32[5] reshape()", 12, 1)]
-    events = [(n, s + 20 * r, d) for r in range(2) for n, s, d in rnd]
-    s = trace.reduce_profile(_profile(_xspace(
-        events, [], [("bench.window", 0, 40)])))
-    reader = spec.metric_reader("cohort_gather_roofline")
-    bytes_per_round = 2 * 4 * 24
-    bound = bytes_per_round / 819e9
-    assert reader.read(_gather_ctx(s, 2)) == pytest.approx(
-        100.0 * 2 * bound / 16e-6)
-    # a count that is not a whole multiple of the rounds cannot be told apart
-    assert reader.read(_gather_ctx(s, 3)) is None
