@@ -39,6 +39,14 @@ Sweep entry points (:func:`run_seed_sweep`, :func:`run_strategy_sweep`)
 vectorize the scan engine with ``jax.vmap`` over per-seed server states, so a
 multi-rebuild experiment cell runs as a single compiled program.
 
+Interactions come in one of two layouts. A dense (users, items) matrix is
+laid out on the device as float32 and closed over by the round programs. A
+CSR triple ``(indptr, indices, (users, items))`` of host arrays, each
+user's item ids in ``indices[indptr[i]:indptr[i + 1]]``, is laid out as
+:class:`UserLists` (int32 on the device, no (users, items) array anywhere)
+and enters the compiled chunk as an argument; the scan engine takes it,
+the other engines refuse it.
+
 Evaluation (Sec. 6.2): every ``eval_every`` rounds, a fixed user sample
 downloads the *full* global model (the paper's inference-time download),
 solves p_i on train data and computes normalized P/R/F1/MAP@10 on the
@@ -47,6 +55,7 @@ smoothing at read-out time.
 """
 from __future__ import annotations
 
+import functools
 import os
 from dataclasses import dataclass, field, replace
 from typing import Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
@@ -239,6 +248,121 @@ class _SimSetup(NamedTuple):
     fault_sched: Optional[FaultSchedule] = None
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["indptr", "indices"],
+                   meta_fields=["num_items", "piece"])
+@dataclass(frozen=True)
+class UserLists:
+    """Each user's item ids on the device: user i holds
+    ``indices[indptr[i]:indptr[i + 1]]``. A pytree of the two int32 arrays,
+    so it enters a compiled program as an argument; ``num_items`` and
+    ``piece`` (the ids the cohort gather walks per step) are static."""
+    indptr: jax.Array          # (N + 1,) int32
+    indices: jax.Array         # (nnz,) int32
+    num_items: int
+    piece: int
+
+
+def _is_lists(x) -> bool:
+    """Whether ``x`` is a CSR triple: a 3-tuple whose last item is a 2-tuple
+    of ints, ``(indptr, indices, (users, items))``."""
+    if not (isinstance(x, tuple) and len(x) == 3):
+        return False
+    shape = x[2]
+    return (isinstance(shape, tuple) and len(shape) == 2
+            and all(isinstance(d, (int, np.integer)) for d in shape))
+
+
+def _checked_lists(x, name: str):
+    """``(indptr int64, indices int32, (users, items))`` on the host, or a
+    ValueError that names what is wrong with the triple."""
+    if not _is_lists(x):
+        raise ValueError(
+            f"{name} must be a CSR triple (indptr, indices, (users, items)) "
+            f"like the other split")
+    indptr, indices, (n, m) = x
+    indptr, indices = np.asarray(indptr), np.asarray(indices)
+    if indptr.ndim != 1 or indptr.shape[0] != n + 1 \
+            or not np.issubdtype(indptr.dtype, np.integer):
+        raise ValueError(
+            f"{name}: indptr must be {n + 1} integers (users + 1), got "
+            f"{indptr.dtype} {indptr.shape}")
+    if indices.ndim != 1 or not np.issubdtype(indices.dtype, np.integer):
+        raise ValueError(f"{name}: indices must be a 1-D integer array, got "
+                         f"{indices.dtype} {indices.shape}")
+    if indices.shape[0] >= 2 ** 31:
+        raise ValueError(f"{name}: {indices.shape[0]} ids do not fit int32 "
+                         f"offsets (2**31 or more)")
+    if indptr[0] != 0 or indptr[-1] != indices.shape[0]:
+        raise ValueError(
+            f"{name}: indptr must run from 0 to len(indices) = "
+            f"{indices.shape[0]}, got {indptr[0]} .. {indptr[-1]}")
+    if np.any(np.diff(indptr) < 0):
+        raise ValueError(f"{name}: indptr is not monotone")
+    if indices.shape[0] and (indices.min() < 0 or indices.max() >= m):
+        raise ValueError(
+            f"{name}: item ids must lie in [0, {m}), got {indices.min()} .. "
+            f"{indices.max()}")
+    return (indptr.astype(np.int64, copy=False),
+            indices.astype(np.int32, copy=False), (int(n), int(m)))
+
+
+def _list_piece(theta: int, num_users: int, num_ids: int) -> int:
+    """Ids the cohort gather walks per step: the power of two at or above an
+    eighth of an average cohort's ids (at least 128). It depends on the data
+    and Theta only, never on the seed, so a new seed compiles nothing; the
+    walk pads a round's ids by less than one piece."""
+    mean = theta * num_ids / max(num_users, 1)
+    return 1 << max(7, int(np.ceil(np.log2(max(mean / 8.0, 1.0)))))
+
+
+def _dense_rows(lists, ids: np.ndarray) -> np.ndarray:
+    """The users ``ids``' rows of the (users, items) matrix, float32, built
+    on the host from the CSR triple."""
+    indptr, indices, (_, m) = lists
+    start, count = indptr[ids], indptr[ids + 1] - indptr[ids]
+    out = np.zeros((len(ids), m), np.float32)
+    row = np.repeat(np.arange(len(ids)), count)
+    pos = np.repeat(start - (np.cumsum(count) - count), count) \
+        + np.arange(int(count.sum()))
+    out[row, indices[pos]] = 1.0
+    return out
+
+
+def _eval_rows(x, ids: jax.Array) -> jax.Array:
+    """The eval users' (E, M) rows: indexed from the dense matrix, or built
+    once from the lists."""
+    if _is_lists(x):
+        return jnp.asarray(_dense_rows(x, np.asarray(ids)))
+    return x[ids]
+
+
+def _lay_out_lists(train_x, test_x, config: FLSimConfig):
+    """Validate both CSR triples, put the train lists on the device under
+    the span ``lists.layout``, and build the set-up. Returns ``(UserLists,
+    _SimSetup)``; the eval users' rows are the only dense rows."""
+    if config.backend != "scan":
+        raise ValueError(
+            f"per-user item lists (a CSR triple) run on backend='scan' only; "
+            f"backend={config.backend!r} takes the dense (users, items) "
+            f"matrix")
+    train_l = _checked_lists(train_x, "train_x")
+    test_l = _checked_lists(test_x, "test_x")
+    if train_l[2] != test_l[2]:
+        raise ValueError(f"train_x is {train_l[2]} and test_x {test_l[2]}: "
+                         f"the splits must have one (users, items) shape")
+    indptr, indices, (n, m) = train_l
+    theta = min(config.theta, n)
+    cap = int(np.sort(np.diff(indptr))[n - theta:].sum())
+    with span("lists.layout", users=n, ids=int(indices.shape[0]), cap=cap):
+        lists = UserLists(
+            indptr=jnp.asarray(indptr.astype(np.int32)),
+            indices=jnp.asarray(indices), num_items=m,
+            piece=_list_piece(theta, n, int(indices.shape[0])))
+        jax.block_until_ready(lists)
+    return lists, _build(train_l, test_l, config)
+
+
 def _num_select(config: FLSimConfig, num_items: int) -> int:
     if config.strategy == "full":
         return num_items
@@ -256,9 +380,11 @@ def _chunk_bounds(rounds: int, eval_every: int) -> List[Tuple[int, int]]:
     return bounds
 
 
-def _build(train_j: jax.Array, test_j: jax.Array,
-           config: FLSimConfig) -> _SimSetup:
+def _build(train_j, test_j, config: FLSimConfig) -> _SimSetup:
     """Pure-data setup shared by every backend: states, cohorts, eval split.
+
+    ``train_j``/``test_j`` are the dense (N, M) matrices or checked CSR
+    triples (:func:`_checked_lists`); only the eval users' rows are dense.
 
     PRNG discipline matches the legacy stateful path: PRNGKey(seed) splits
     into (init, users, eval); the selection stream is PRNGKey(seed+13) split
@@ -303,7 +429,8 @@ def _build(train_j: jax.Array, test_j: jax.Array,
             f"one cohort block per device; blocks_per_commit="
             f"{config.blocks_per_commit} conflicts (leave it at 1 or set "
             f"it equal to mesh_shards)")
-    num_users, num_items = train_j.shape
+    num_users, num_items = train_j[2] if _is_lists(train_j) \
+        else train_j.shape
     key = jax.random.PRNGKey(config.seed)
     k_init, _k_users, k_eval = jax.random.split(key, 3)
 
@@ -367,7 +494,8 @@ def _build(train_j: jax.Array, test_j: jax.Array,
         cf_cfg=cf_cfg, sel_cfg=sel_cfg, srv_cfg=srv_cfg,
         codec_cfg=codec_cfg, state0=state0,
         cohorts=cohorts, staleness=staleness,
-        eval_train=train_j[eval_ids], eval_test=test_j[eval_ids],
+        eval_train=_eval_rows(train_j, eval_ids),
+        eval_test=_eval_rows(test_j, eval_ids),
         fault_sched=fault_sched,
     )
 
@@ -394,17 +522,61 @@ def _staleness_schedule(config: FLSimConfig) -> np.ndarray:
     return np.minimum(s, np.arange(rounds)).astype(np.int32)
 
 
-def _cohort_block(train: jax.Array, ids: jax.Array,
-                  idx: jax.Array) -> jax.Array:
+def _cohort_block(train, ids: jax.Array, idx: jax.Array) -> jax.Array:
     """``train[ids][:, idx]``: the cohort's (len(ids), len(idx)) block.
 
     Gathered in two stages of contiguous slices: the cohort's user rows
     whole, (B, M), then the payload columns as rows of that slab's
     transpose, (M_s, B). The one-step ``train[ids[:, None], idx[None, :]]``
     lowers to B x M_s single-element slices, which cost per element on the
-    TPU. Both are exact, so the block is bit-equal either way.
+    TPU. Both are exact, so the block is bit-equal either way. From
+    :class:`UserLists` it is :func:`_cohort_block_lists`, bit-equal too.
     """
+    if isinstance(train, UserLists):
+        return _cohort_block_lists(train, ids, idx)
     return train[ids].T[idx].T
+
+
+def _cohort_block_lists(lists: UserLists, ids: jax.Array,
+                        idx: jax.Array) -> jax.Array:
+    """The cohort's (B, M_s) block from per-user item lists, as 0/1 float32.
+
+    Each selected item maps to its column through an (M,) int32 map (M_s,
+    out of range, elsewhere). The cohort's ids, user after user, are walked
+    ``lists.piece`` at a time in a ``while_loop`` bounded by their true
+    count: each id finds its user by its offset in the walk, its column by
+    the map, and sets a one straight into the block; ids of unselected
+    items and the walk's padding are dropped. Padded work is under one piece
+    a round, and no shape depends on the cohort. The block is written as
+    its (M_s, B) transpose, the layout the dense path's column gather
+    leaves, so the round's consumers compile alike and the state is
+    bit-equal to the dense path's.
+    """
+    b, m_s, piece = ids.shape[0], idx.shape[0], lists.piece
+    start = lists.indptr[ids]
+    count = lists.indptr[ids + 1] - start
+    end = jnp.cumsum(count)                          # walk offset past user
+    first = end - count
+    total = end[-1]
+    col = jnp.full((lists.num_items,), m_s, jnp.int32).at[idx].set(
+        jnp.arange(m_s, dtype=jnp.int32))
+
+    def walk(carry):
+        k, xt = carry
+        j = k * piece + jnp.arange(piece, dtype=jnp.int32)
+        row = jnp.searchsorted(end, j, side="right",
+                               method="compare_all").astype(jnp.int32)
+        used = j < total                             # else row == b: dropped
+        r = jnp.minimum(row, b - 1)
+        item = lists.indices[jnp.where(used, start[r] + j - first[r], 0)]
+        c = jnp.where(used, col[item], m_s)
+        return k + 1, xt.at[c, row].set(1.0, mode="drop")
+
+    steps = (total + piece - 1) // piece
+    _, xt = jax.lax.while_loop(
+        lambda carry: carry[0] < steps, walk,
+        (jnp.int32(0), jnp.zeros((m_s, b), jnp.float32)))
+    return xt.T
 
 
 def _blocked_cohort_x(train_j: jax.Array, ids: jax.Array, shards: int,
@@ -895,10 +1067,17 @@ def run_fcf_simulation(
     absent, none of this exists in the compiled programs; the spans still
     reach any profiler session that is running, and the round's phases
     carry their ``fl_*`` scope names in the programs' debug info.
+
+    ``train_x``/``test_x`` are dense (N, M) matrices, or CSR triples
+    ``(indptr, indices, (N, M))`` of host arrays (scan backend only), which
+    set-up lays out on the device once under the span ``lists.layout``.
     """
-    train_j = jnp.asarray(train_x, jnp.float32)
-    test_j = jnp.asarray(test_x, jnp.float32)
-    setup = _build(train_j, test_j, config)
+    if _is_lists(train_x):
+        train_j, setup = _lay_out_lists(train_x, test_x, config)
+    else:
+        train_j = jnp.asarray(train_x, jnp.float32)
+        test_j = jnp.asarray(test_x, jnp.float32)
+        setup = _build(train_j, test_j, config)
     record = config.record_selections
     obs = config.obs if (config.obs is not None
                          and config.obs.enabled) else None
@@ -1034,13 +1213,21 @@ def _run_single(train_j, setup, config, record, obs, csv_path) -> SimResult:
                             st, jnp.asarray(cohorts),
                             jnp.asarray(np.asarray(staleness), jnp.int32))
             else:
-                round_fn = _make_round_fn(train_j, setup,
-                                          config.cohort_shards,
-                                          telemetry=obs is not None,
-                                          fault_on=fault_on)
+                # per-user lists enter the chunk as its argument
+                # ``data_arg``; the dense matrix stays closed over, and
+                # ``data_arg`` is None, so its programs are as they were
+                data = train_j if isinstance(train_j, UserLists) else None
+
+                def round_fn_of(data_arg):
+                    return _make_round_fn(
+                        train_j if data_arg is None else data_arg, setup,
+                        config.cohort_shards, telemetry=obs is not None,
+                        fault_on=fault_on)
 
                 if obs is not None:
-                    def scan_chunk(st, tel, cohorts):
+                    def scan_chunk(st, tel, cohorts, data_arg):
+                        round_fn = round_fn_of(data_arg)
+
                         def body(carry, cohort):
                             s, ts = carry
                             s, aux = round_fn(s, cohort)
@@ -1059,10 +1246,12 @@ def _run_single(train_j, setup, config, record, obs, csv_path) -> SimResult:
 
                     def run_chunk(st, cohorts, staleness=None):
                         st, tel_holder[0], ys = compiled(
-                            st, tel_holder[0], jnp.asarray(cohorts))
+                            st, tel_holder[0], jnp.asarray(cohorts), data)
                         return st, ys
                 elif fault_on:
-                    def scan_chunk(st, cohorts, rf):
+                    def scan_chunk(st, cohorts, rf, data_arg):
+                        round_fn = round_fn_of(data_arg)
+
                         def body(s, xs):
                             cohort, rf_t = xs
                             s, aux = round_fn(s, cohort, rf_t)
@@ -1072,9 +1261,11 @@ def _run_single(train_j, setup, config, record, obs, csv_path) -> SimResult:
                     compiled = jax.jit(scan_chunk)
 
                     def run_chunk(st, cohorts, staleness=None, rf=None):
-                        return compiled(st, jnp.asarray(cohorts), rf)
+                        return compiled(st, jnp.asarray(cohorts), rf, data)
                 else:
-                    def scan_chunk(st, cohorts):
+                    def scan_chunk(st, cohorts, data_arg):
+                        round_fn = round_fn_of(data_arg)
+
                         def body(s, cohort):
                             s, aux = round_fn(s, cohort)
                             return s, (aux if record else None)
@@ -1083,7 +1274,7 @@ def _run_single(train_j, setup, config, record, obs, csv_path) -> SimResult:
                     compiled = jax.jit(scan_chunk)
 
                     def run_chunk(st, cohorts, staleness=None):
-                        return compiled(st, jnp.asarray(cohorts))
+                        return compiled(st, jnp.asarray(cohorts), data)
 
             for start, end in _chunk_bounds(config.rounds,
                                             config.eval_every):
@@ -1208,6 +1399,11 @@ def run_seed_sweep(
     """
     if not seeds:
         return []
+    if _is_lists(train_x):
+        raise ValueError(
+            "run_seed_sweep takes the dense (users, items) matrix; per-user "
+            "item lists (a CSR triple) run through run_fcf_simulation with "
+            "backend='scan'")
     if config.obs is not None and config.obs.enabled:
         raise ValueError(
             "config.obs telemetry is single-run only (one stream per "

@@ -297,6 +297,32 @@ def test_tracer_nested_spans_schema_and_restore(tmp_path):
     assert len(lines) == 1 and validate_span_event(lines[0]) == []
 
 
+def test_lists_layout_span_carries_users_ids_and_cap():
+    """Per-user item lists are laid out on the device in set-up under the
+    span ``lists.layout``: the users, the train ids, and the most ids the
+    walk of one cohort can meet (the Theta largest degrees together)."""
+    train, test = _mini_data()
+
+    def csr(x):
+        rows, cols = np.nonzero(x)
+        indptr = np.zeros(x.shape[0] + 1, np.int64)
+        np.cumsum(np.bincount(rows, minlength=x.shape[0]), out=indptr[1:])
+        return indptr, cols.astype(np.int32), x.shape
+
+    degrees = np.sort(train.sum(axis=1))
+    tracer = Tracer()
+    prev = install_tracer(tracer)
+    try:
+        run_fcf_simulation(csr(train), csr(test),
+                           _cfg("scan", rounds=3, eval_every=3))
+    finally:
+        install_tracer(prev)
+    (event,) = [e for e in tracer.events if e["name"] == "lists.layout"]
+    assert event["attrs"] == {"users": 60, "ids": int(train.sum()),
+                              "cap": int(degrees[-10:].sum())}
+    assert validate_span_event(event) == []
+
+
 def test_null_tracer_span_is_shared_noop():
     """The default tracer hands back the bare profiler annotation of the
     span's name — one enter and exit with no profiler running — and

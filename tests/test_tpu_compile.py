@@ -23,7 +23,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.federated.simulation import _blocked_cohort_x
+from repro.federated.simulation import (UserLists, _blocked_cohort_x,
+                                        _list_piece)
 from repro.kernels import fcf_grad as fcf
 from repro.kernels import moment_quant as mq
 from repro.kernels import ops
@@ -147,6 +148,39 @@ def test_cohort_gather_compiles_to_slice_gathers_for_tpu(arg, data):
              for g in re.findall(r" gather\(.*?slice_sizes=\{([0-9,]+)\}",
                                  text)]
     assert sorted(sizes) == [(1, theta), (1, items)], sizes
+
+
+# MovieLens-25M as per-user item lists: its train split's users and ids,
+# items, Theta and M_s at keep 0.1
+ML25M_USERS, ML25M_IDS, ML25M_ITEMS = 162_541, 20_050_397, 62_423
+ML25M_THETA, ML25M_M_S = 1_000, 6_242
+
+
+def test_fcf_grad_compiles_for_tpu_at_ml25m(arg):
+    k = 25
+    _assert_kernel(fcf.fcf_grad, arg((ML25M_M_S, k)), arg((ML25M_THETA, k)),
+                   arg((ML25M_THETA, ML25M_M_S)), alpha=4.0, l2=0.0,
+                   block_m=256)
+
+
+def test_list_cohort_gather_compiles_for_tpu(arg):
+    """The list gather at MovieLens-25M's size: the ids stay a parameter of
+    the program, and no (Theta, M) array is made on the way to the block."""
+    piece = _list_piece(ML25M_THETA, ML25M_USERS, ML25M_IDS)
+
+    def block(indptr, indices, ids, idx):
+        lists = UserLists(indptr=indptr, indices=indices,
+                          num_items=ML25M_ITEMS, piece=piece)
+        return _blocked_cohort_x(lists, ids, 1, ML25M_THETA)(idx)
+
+    text = jax.jit(block).lower(
+        arg((ML25M_USERS + 1,), jnp.int32), arg((ML25M_IDS,), jnp.int32),
+        arg((ML25M_THETA,), jnp.int32),
+        arg((ML25M_M_S,), jnp.int32)).compile().as_text()
+    assert re.search(rf"s32\[{ML25M_IDS}\]\S* parameter\(1\)", text)
+    assert f"{ML25M_THETA},{ML25M_ITEMS}]" not in text
+    assert f"{ML25M_ITEMS},{ML25M_THETA}]" not in text
+    assert " while(" in text
 
 
 def _score_args(arg, codec, m, k):
