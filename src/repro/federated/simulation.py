@@ -254,13 +254,19 @@ class _SimSetup(NamedTuple):
 @dataclass(frozen=True)
 class UserLists:
     """Each user's item ids on the device: user i holds
-    ``indices[indptr[i]:indptr[i + 1]]``. A pytree of the two int32 arrays,
-    so it enters a compiled program as an argument; ``num_items`` and
-    ``piece`` (the ids the cohort gather walks per step) are static."""
+    ``indices.reshape(-1)[indptr[i]:indptr[i + 1]]``. The ids are laid out
+    in rows of ``chunk`` (zero-padded at the end), so the cohort gather reads
+    them a whole row at a time. A pytree of the two int32 arrays, so it
+    enters a compiled program as an argument; ``num_items`` and ``piece``
+    (the id slots the cohort gather walks per step) are static."""
     indptr: jax.Array          # (N + 1,) int32
-    indices: jax.Array         # (nnz,) int32
+    indices: jax.Array         # (ceil(nnz / chunk), chunk) int32
     num_items: int
     piece: int
+
+    @property
+    def chunk(self) -> int:
+        return self.indices.shape[1]
 
 
 def _is_lists(x) -> bool:
@@ -308,12 +314,36 @@ def _checked_lists(x, name: str):
 
 
 def _list_piece(theta: int, num_users: int, num_ids: int) -> int:
-    """Ids the cohort gather walks per step: the power of two at or above an
-    eighth of an average cohort's ids (at least 128). It depends on the data
-    and Theta only, never on the seed, so a new seed compiles nothing; the
-    walk pads a round's ids by less than one piece."""
+    """Id slots the cohort gather walks per step: the power of two at or
+    above an eighth of an average cohort's ids (at least 128). It depends on
+    the data and Theta only, never on the seed, so a new seed compiles
+    nothing; the walk pads a round by less than one piece."""
     mean = theta * num_ids / max(num_users, 1)
     return 1 << max(7, int(np.ceil(np.log2(max(mean / 8.0, 1.0)))))
+
+
+def _list_chunk(degrees: np.ndarray) -> int:
+    """Ids in one row of the laid-out lists, which the cohort gather reads
+    whole: the power of two at or below a quarter of the median degree, at
+    least 8. Set by the data alone. A list of d ids touches about ``d /
+    chunk + 1`` rows, so short lists get short rows."""
+    quarter = float(np.median(degrees)) / 4.0 if len(degrees) else 0.0
+    return 1 << max(3, int(np.floor(np.log2(max(quarter, 1.0)))))
+
+
+def _rows_touched(start, stop, chunk: int):
+    """Rows of ``chunk`` ids that each list ``[start, stop)`` of the flat
+    ids touches (NumPy or JAX arrays)."""
+    return (stop > start) * ((stop - 1) // chunk - start // chunk + 1)
+
+
+def _id_rows(indices: np.ndarray, chunk: int) -> np.ndarray:
+    """The flat ids as (ceil(nnz / chunk), chunk) rows, zero-padded (one row
+    at least)."""
+    rows = max(1, -(-indices.shape[0] // chunk))
+    out = np.zeros(rows * chunk, np.int32)
+    out[:indices.shape[0]] = indices
+    return out.reshape(rows, chunk)
 
 
 def _dense_rows(lists, ids: np.ndarray) -> np.ndarray:
@@ -353,11 +383,16 @@ def _lay_out_lists(train_x, test_x, config: FLSimConfig):
                          f"the splits must have one (users, items) shape")
     indptr, indices, (n, m) = train_l
     theta = min(config.theta, n)
-    cap = int(np.sort(np.diff(indptr))[n - theta:].sum())
-    with span("lists.layout", users=n, ids=int(indices.shape[0]), cap=cap):
+    degrees = np.diff(indptr)
+    chunk = _list_chunk(degrees)
+    rows = _rows_touched(indptr[:-1], indptr[1:], chunk)
+    cap = int(np.sort(degrees)[n - theta:].sum())
+    cap_chunks = int(np.sort(rows)[n - theta:].sum())
+    with span("lists.layout", users=n, ids=int(indices.shape[0]), cap=cap,
+              chunk=chunk, cap_chunks=cap_chunks):
         lists = UserLists(
             indptr=jnp.asarray(indptr.astype(np.int32)),
-            indices=jnp.asarray(indices), num_items=m,
+            indices=jnp.asarray(_id_rows(indices, chunk)), num_items=m,
             piece=_list_piece(theta, n, int(indices.shape[0])))
         jax.block_until_ready(lists)
     return lists, _build(train_l, test_l, config)
@@ -542,41 +577,51 @@ def _cohort_block_lists(lists: UserLists, ids: jax.Array,
     """The cohort's (B, M_s) block from per-user item lists, as 0/1 float32.
 
     Each selected item maps to its column through an (M,) int32 map (M_s,
-    out of range, elsewhere). The cohort's ids, user after user, are walked
-    ``lists.piece`` at a time in a ``while_loop`` bounded by their true
-    count: each id finds its user by its offset in the walk, its column by
-    the map, and sets a one straight into the block; ids of unselected
-    items and the walk's padding are dropped. Padded work is under one piece
-    a round, and no shape depends on the cohort. The block is written as
-    its (M_s, B) transpose, the layout the dense path's column gather
-    leaves, so the round's consumers compile alike and the state is
-    bit-equal to the dense path's.
+    out of range, elsewhere). A user's ids span whole rows of
+    ``lists.indices``; the cohort's rows, user after user, are walked
+    ``lists.piece // lists.chunk`` at a time in a ``while_loop`` bounded by
+    their true count: each row finds its user by its place in the walk and
+    is gathered whole, and each of its ids finds its column by the map and
+    sets a one straight into the block. Ids of the row's other users, of
+    unselected items, and the walk's padding are dropped. No shape depends
+    on the cohort. The block is set flat, as its (M_s, B) transpose, the
+    layout the dense path's column gather leaves, so the round's consumers
+    compile alike and the state is bit-equal to the dense path's. It is set
+    as int8, a quarter of float32's bytes, so the walk does not crowd the
+    round's tables out of the TPU's VMEM; 0 and 1 convert exactly.
     """
-    b, m_s, piece = ids.shape[0], idx.shape[0], lists.piece
+    b, m_s, c = ids.shape[0], idx.shape[0], lists.chunk
+    per_step = max(1, lists.piece // c)
     start = lists.indptr[ids]
-    count = lists.indptr[ids + 1] - start
-    end = jnp.cumsum(count)                          # walk offset past user
-    first = end - count
+    stop = lists.indptr[ids + 1]
+    rows = _rows_touched(start, stop, c)
+    end = jnp.cumsum(rows)                       # walk offset past user
+    first = end - rows
     total = end[-1]
     col = jnp.full((lists.num_items,), m_s, jnp.int32).at[idx].set(
         jnp.arange(m_s, dtype=jnp.int32))
+    lane = jnp.arange(c, dtype=jnp.int32)
+    last_row = lists.indices.shape[0] - 1
 
     def walk(carry):
-        k, xt = carry
-        j = k * piece + jnp.arange(piece, dtype=jnp.int32)
-        row = jnp.searchsorted(end, j, side="right",
-                               method="compare_all").astype(jnp.int32)
-        used = j < total                             # else row == b: dropped
-        r = jnp.minimum(row, b - 1)
-        item = lists.indices[jnp.where(used, start[r] + j - first[r], 0)]
-        c = jnp.where(used, col[item], m_s)
-        return k + 1, xt.at[c, row].set(1.0, mode="drop")
+        k, flat = carry
+        j = k * per_step + jnp.arange(per_step, dtype=jnp.int32)
+        user = jnp.searchsorted(end, j, side="right",
+                                method="compare_all").astype(jnp.int32)
+        r = jnp.minimum(user, b - 1)                 # user == b: past the end
+        g = jnp.minimum(start[r] // c + j - first[r], last_row)
+        p = g[:, None] * c + lane                    # (P, C) flat positions
+        used = (j < total)[:, None] & (p >= start[r][:, None]) \
+            & (p < stop[r][:, None])
+        cols = jnp.where(used, col[lists.indices[g]], m_s)
+        at = jnp.where(cols < m_s, cols * b + r[:, None], m_s * b)
+        return k + 1, flat.at[at].set(1, mode="drop")
 
-    steps = (total + piece - 1) // piece
-    _, xt = jax.lax.while_loop(
+    steps = (total + per_step - 1) // per_step
+    _, flat = jax.lax.while_loop(
         lambda carry: carry[0] < steps, walk,
-        (jnp.int32(0), jnp.zeros((m_s, b), jnp.float32)))
-    return xt.T
+        (jnp.int32(0), jnp.zeros((m_s * b,), jnp.int8)))
+    return flat.reshape(m_s, b).T.astype(jnp.float32)
 
 
 def _blocked_cohort_x(train_j: jax.Array, ids: jax.Array, shards: int,

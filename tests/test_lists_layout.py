@@ -64,21 +64,34 @@ def _splits(seed=0, users=USERS, items=ITEMS):
     return tuple(out)
 
 
-def _device_lists(triple, piece):
+def _device_lists(triple, piece, chunk=None):
     indptr, indices, (_, m) = triple
+    chunk = chunk or sim._list_chunk(np.diff(indptr))
     return sim.UserLists(indptr=jnp.asarray(indptr.astype(np.int32)),
-                         indices=jnp.asarray(indices), num_items=m,
-                         piece=piece)
+                         indices=jnp.asarray(sim._id_rows(indices, chunk)),
+                         num_items=m, piece=piece)
 
 
-def _assert_blocks_equal(triple, ids, idx, piece):
+def _assert_blocks_equal(triple, ids, idx, piece, chunk=None):
     ids, idx = jnp.asarray(ids, jnp.int32), jnp.asarray(idx, jnp.int32)
-    got = jax.jit(sim._cohort_block)(_device_lists(triple, piece), ids, idx)
+    got = jax.jit(sim._cohort_block)(_device_lists(triple, piece, chunk),
+                                     ids, idx)
     want = jax.jit(sim._cohort_block)(jnp.asarray(_dense(triple)), ids, idx)
     assert got.dtype == want.dtype == jnp.float32
     assert got.shape == (ids.shape[0], idx.shape[0])
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     return got
+
+
+def _triple_of(deg, items=ITEMS, seed=0):
+    """Sorted per-user item ids of the given degrees."""
+    rng = np.random.default_rng(seed)
+    indptr = np.zeros(len(deg) + 1, np.int64)
+    np.cumsum(deg, out=indptr[1:])
+    indices = np.concatenate(
+        [np.sort(rng.choice(items, d, replace=False)) for d in deg]
+        + [np.zeros(0, np.int64)]).astype(np.int32)
+    return indptr, indices, (len(deg), items)
 
 
 # --------------------------------------------------------------------- #
@@ -117,25 +130,109 @@ def test_block_of_the_heaviest_users():
     _assert_blocks_equal(triple, heavy, idx, 16)
 
 
+def _rows(indptr, chunk):
+    """Rows of ``chunk`` ids that each user's list touches, counted."""
+    flat = np.arange(indptr[-1]) // chunk
+    return np.array([len(set(flat[a:b])) for a, b in zip(indptr, indptr[1:])])
+
+
 @pytest.mark.parametrize("extra", [0, 1], ids=["exactly", "one_more"])
 def test_block_when_the_ids_fill_the_capacity_or_one_more_than_a_piece(
         extra):
-    """The Theta heaviest users hold the capacity (the most ids a round can
-    walk): a piece of exactly that many walks it in one step, and a piece
-    one smaller leaves one id for a second step."""
+    """The Theta users whose lists touch the most rows hold the capacity
+    (the most rows a round can walk): a piece of exactly that many rows
+    walks it in one step, and a piece one row smaller leaves one row for a
+    second step."""
     triple = _triple()
-    theta = 6
-    deg = np.diff(triple[0])
-    heavy = np.argsort(deg)[-theta:]
-    cap = int(np.sort(deg)[-theta:].sum())
-    assert int(deg[heavy].sum()) == cap
-    _assert_blocks_equal(triple, heavy, np.arange(ITEMS), cap - extra)
+    theta, chunk = 6, 8
+    rows = _rows(triple[0], chunk)
+    heavy = np.argsort(rows)[-theta:]
+    cap_rows = int(np.sort(rows)[-theta:].sum())
+    assert int(rows[heavy].sum()) == cap_rows
+    _assert_blocks_equal(triple, heavy, np.arange(ITEMS),
+                         (cap_rows - extra) * chunk, chunk)
+
+
+C = 8
+# name -> (degrees, cohort, rows per step, None for all the cohort's rows
+# less ``short``): users of degree C-1, C, C+1 and 2C at offsets that do and
+# do not start a row, the last user of ``indices`` (its final row is padded
+# past the ids' end), a cohort whose rows fill exactly one step or need one
+# more, and lists shorter than one row overall
+CHUNK_EDGES = {
+    "degrees_around_the_chunk": (
+        [C - 1, C, C + 1, 2 * C, 0, 1, 3 * C - 1, 5, C, 2 * C, C + 1],
+        list(range(11)), 2, 0),
+    "last_user_in_a_padded_row": (
+        [3 * C, C + 3, 2, 2 * C, C + 1], [4, 1, 3], 4, 0),
+    "last_user_shorter_than_a_chunk": (
+        [3 * C, C + 3, 2 * C, 2], [3, 0], 2, 0),
+    "rows_fill_one_step": (
+        [C, C + 1, 2 * C, C - 1, 3, 4 * C], [0, 1, 2, 5], None, 0),
+    "rows_need_one_more_step": (
+        [C, C + 1, 2 * C, C - 1, 3, 4 * C], [0, 1, 2, 5], None, 1),
+    "all_lists_shorter_than_a_chunk": (
+        [1, 2, 0, 3], [3, 1, 2, 0], 2, 0),
+}
+
+
+@pytest.mark.parametrize("chunk", [C, 2 * C])
+@pytest.mark.parametrize("case", sorted(CHUNK_EDGES))
+def test_block_from_lists_equals_dense_at_the_chunk_edges(case, chunk):
+    deg, ids, per_step, short = CHUNK_EDGES[case]
+    triple = _triple_of(np.asarray(deg), items=64, seed=len(case))
+    if per_step is None:
+        per_step = int(_rows(triple[0], chunk)[ids].sum()) - short
+    # item 0, the rows' padding, is always among the columns
+    for idx in (np.random.default_rng(9).choice(np.arange(1, 64), 40,
+                                                 replace=False),
+                np.arange(64)):
+        _assert_blocks_equal(triple, ids, idx, per_step * chunk, chunk)
 
 
 def test_list_piece_is_set_by_the_data_and_theta_alone():
     # MovieLens-25M's train lists: 20,050,397 ids over 162,541 users
     assert sim._list_piece(1000, 162_541, 20_050_397) == 16_384
     assert sim._list_piece(10, 60, 1_400) == 128
+
+
+def _ml25m_train_degrees():
+    """MovieLens-25M's train degrees: the benchmark generator's degree draw
+    (the draws before it, then ``user_degrees``) and its per-user cut."""
+    from bench.harness import data
+    from bench.harness.device import ROOT
+
+    ds = json.loads((ROOT / "bench/configs/fcf-ml25m.json").read_text())
+    ds = ds["data"]
+    n, m, k0 = ds["num_users"], ds["num_items"], ds["latent_dim"]
+    rng = np.random.default_rng(ds["seed"])
+    rng.standard_normal((n, k0))
+    rng.standard_normal((m, k0))
+    rng.permutation(m)
+    deg = data.user_degrees(n, m, ds["num_interactions"], ds["min_degree"],
+                            rng)
+    return np.array([data._cut(int(d), ds["train_frac"]) for d in deg])
+
+
+def test_list_chunk_and_chunks_per_step_are_set_by_the_data_alone():
+    deg = _ml25m_train_degrees()
+    assert int(deg.sum()) == 20_050_397 and np.median(deg) == 74
+    chunk = sim._list_chunk(deg)
+    assert chunk == 16                        # a quarter of 74, down
+    assert sim._list_piece(1000, 162_541, 20_050_397) // chunk == 1024
+    # the tiny sets here have medians of a dozen or less: the floor
+    assert sim._list_chunk(np.diff(_triple()[0])) == 8
+    assert sim._list_chunk(np.array([1, 2, 0, 3])) == 8
+    assert sim._list_chunk(np.zeros(0, np.int64)) == 8
+    assert sim._list_chunk(np.full(10, 64)) == 16
+    assert sim._list_chunk(np.full(10, 63)) == 8
+
+
+def test_id_rows_pad_the_last_row_and_keep_one_row():
+    np.testing.assert_array_equal(
+        sim._id_rows(np.arange(1, 11, dtype=np.int32), 4),
+        [[1, 2, 3, 4], [5, 6, 7, 8], [9, 10, 0, 0]])
+    assert sim._id_rows(np.zeros(0, np.int32), 8).shape == (1, 8)
 
 
 # --------------------------------------------------------------------- #
@@ -252,7 +349,8 @@ def test_compiled_programs_hold_no_users_by_items_array(tmp_path,
                                                          monkeypatch):
     users, items = 61, 293               # shapes no other array has
     tr, te = _splits(seed=7, users=users, items=items)
-    nnz = tr[1].shape[0]
+    width = sim._list_chunk(np.diff(tr[0]))
+    rows = sim._id_rows(tr[1], width).shape[0]
     chunks = _ChunkText(monkeypatch)
     was = jax.config.values["jax_dump_ir_to"]
     jax.config.update("jax_dump_ir_to", str(tmp_path))
@@ -268,9 +366,10 @@ def test_compiled_programs_hold_no_users_by_items_array(tmp_path,
     (chunk,) = chunks.texts
     main = re.search(r"func\.func public @main\((.*?)\)\s*->", chunk,
                      re.S).group(1)
-    assert f"tensor<{nnz}xi32>" in main                   # the ids
+    assert f"tensor<{rows}x{width}xi32>" in main          # the ids
     assert f"tensor<{users + 1}xi32>" in main             # the offsets
-    assert not re.search(rf"constant dense<.*tensor<{nnz}xi32>", chunk)
+    assert not re.search(rf"constant dense<.*tensor<{rows}x{width}xi32>",
+                         chunk)
 
 
 def test_a_new_seed_lowers_the_same_chunk_program(monkeypatch):

@@ -299,8 +299,10 @@ def test_tracer_nested_spans_schema_and_restore(tmp_path):
 
 def test_lists_layout_span_carries_users_ids_and_cap():
     """Per-user item lists are laid out on the device in set-up under the
-    span ``lists.layout``: the users, the train ids, and the most ids the
-    walk of one cohort can meet (the Theta largest degrees together)."""
+    span ``lists.layout``: the users, the train ids, the most ids the walk
+    of one cohort can meet (the Theta largest degrees together), the ids in
+    one row of the layout (the walk reads whole rows), and the most rows one
+    cohort's lists touch."""
     train, test = _mini_data()
 
     def csr(x):
@@ -318,8 +320,12 @@ def test_lists_layout_span_carries_users_ids_and_cap():
     finally:
         install_tracer(prev)
     (event,) = [e for e in tracer.events if e["name"] == "lists.layout"]
-    assert event["attrs"] == {"users": 60, "ids": int(train.sum()),
-                              "cap": int(degrees[-10:].sum())}
+    indptr = csr(train)[0]
+    rows = (indptr[1:] - 1) // 8 - indptr[:-1] // 8 + 1   # no empty list
+    assert event["attrs"] == {
+        "users": 60, "ids": int(train.sum()), "cap": int(degrees[-10:].sum()),
+        "chunk": 8,                       # the floor: median degree < 64
+        "cap_chunks": int(np.sort(rows)[-10:].sum())}
     assert validate_span_event(event) == []
 
 

@@ -154,6 +154,7 @@ def test_cohort_gather_compiles_to_slice_gathers_for_tpu(arg, data):
 # items, Theta and M_s at keep 0.1
 ML25M_USERS, ML25M_IDS, ML25M_ITEMS = 162_541, 20_050_397, 62_423
 ML25M_THETA, ML25M_M_S = 1_000, 6_242
+ML25M_CHUNK = 16                # _list_chunk of its train degrees (median 74)
 
 
 def test_fcf_grad_compiles_for_tpu_at_ml25m(arg):
@@ -163,10 +164,25 @@ def test_fcf_grad_compiles_for_tpu_at_ml25m(arg):
                    block_m=256)
 
 
+def _gathers_from(text, shape):
+    """Slice sizes of the gathers whose operand is an array of ``shape``
+    (a parameter of the fusion that holds the gather)."""
+    defs = dict(re.findall(r"(%\S+) = (\w+\[[0-9,]*\])", text))
+    return [tuple(int(v) for v in sizes.split(","))
+            for operand, sizes in re.findall(
+                r" gather\((%[^,\s]+), .*?slice_sizes=\{([0-9,]+)\}", text)
+            if defs.get(operand) == shape]
+
+
 def test_list_cohort_gather_compiles_for_tpu(arg):
     """The list gather at MovieLens-25M's size: the ids stay a parameter of
-    the program, and no (Theta, M) array is made on the way to the block."""
+    the program, laid out in rows of the chunk width, and are read only by
+    gathers of whole rows, never one id at a time; no (Theta, M) array is
+    made on the way to the block, and the walk sets it as bytes (a float32
+    carry crowds the round's tables out of VMEM in the whole chunk)."""
     piece = _list_piece(ML25M_THETA, ML25M_USERS, ML25M_IDS)
+    chunk = ML25M_CHUNK
+    rows = -(-ML25M_IDS // chunk)
 
     def block(indptr, indices, ids, idx):
         lists = UserLists(indptr=indptr, indices=indices,
@@ -174,13 +190,19 @@ def test_list_cohort_gather_compiles_for_tpu(arg):
         return _blocked_cohort_x(lists, ids, 1, ML25M_THETA)(idx)
 
     text = jax.jit(block).lower(
-        arg((ML25M_USERS + 1,), jnp.int32), arg((ML25M_IDS,), jnp.int32),
+        arg((ML25M_USERS + 1,), jnp.int32), arg((rows, chunk), jnp.int32),
         arg((ML25M_THETA,), jnp.int32),
         arg((ML25M_M_S,), jnp.int32)).compile().as_text()
-    assert re.search(rf"s32\[{ML25M_IDS}\]\S* parameter\(1\)", text)
+    assert re.search(rf"s32\[{rows},{chunk}\]\S* parameter\(1\)", text)
+    sizes = _gathers_from(text, f"s32[{rows},{chunk}]")
+    assert sizes and set(sizes) == {(1, chunk)}, sizes
+    assert f"s32[{rows * chunk}]" not in text       # never flattened
     assert f"{ML25M_THETA},{ML25M_ITEMS}]" not in text
     assert f"{ML25M_ITEMS},{ML25M_THETA}]" not in text
     assert " while(" in text
+    scattered = set(re.findall(r"= (\w+)\[(\d+)\]\S* scatter\(", text))
+    assert ("s8", str(ML25M_THETA * ML25M_M_S)) in scattered, scattered
+    assert not any(dtype == "f32" for dtype, _ in scattered), scattered
 
 
 def _score_args(arg, codec, m, k):
