@@ -77,51 +77,19 @@ def _make_engine_sampler(train, test, cfg, rounds: int = 60):
     import jax
     import jax.numpy as jnp
 
-    from repro.federated.simulation import (
-        _build, _make_async_round_fn, _make_round_fn,
-    )
+    from repro.federated.simulation import _build, _make_chunk_runner
 
     train_j = jnp.asarray(train, jnp.float32)
     setup = _build(train_j, jnp.asarray(test, jnp.float32), cfg)
-    cohorts = jnp.asarray(
-        np.resize(setup.cohorts, (rounds,) + setup.cohorts.shape[1:]))
-
-    if cfg.backend == "async":
-        round_fn = _make_async_round_fn(train_j, setup,
-                                        cfg.blocks_per_commit)
-        stale = jnp.asarray(
-            np.resize(setup.staleness, (rounds,)).astype(np.int32))
-
-        def scan_chunk(state, ch, st_sched):
-            def body(st, xs):
-                cohort, s_t = xs
-                st, _ = round_fn(st, cohort, s_t)
-                return st, None
-            return jax.lax.scan(body, state, (ch, st_sched))
-
-        compiled = jax.jit(scan_chunk)
-
-        def run_once():
-            state, _ = compiled(setup.state0, cohorts, stale)
-            jax.block_until_ready(state.q)
-    else:
-        round_fn = _make_round_fn(train_j, setup, cfg.cohort_shards)
-
-        def scan_chunk(state, ch):
-            def body(st, cohort):
-                st, _ = round_fn(st, cohort)
-                return st, None
-            return jax.lax.scan(body, state, ch)
-
-        compiled = jax.jit(scan_chunk)
-
-        def run_once():
-            state, _ = compiled(setup.state0, cohorts)
-            jax.block_until_ready(state.q)
+    setup = setup._replace(
+        cohorts=np.resize(setup.cohorts, (rounds,) + setup.cohorts.shape[1:]),
+        staleness=np.resize(setup.staleness, (rounds,)).astype(np.int32))
+    run_chunk, state0 = _make_chunk_runner(train_j, setup, cfg, False, None)
 
     def sample() -> float:
         t0 = time.perf_counter()
-        run_once()
+        state, _ = run_chunk(state0, 0, rounds)
+        jax.block_until_ready(state.q)
         return rounds / (time.perf_counter() - t0)
 
     sample()                                       # warmup / compile

@@ -92,7 +92,8 @@ def _rounds_per_sec(train, test, cfg: FLSimConfig, rounds: int = 60) -> float:
     """Steady-state scan throughput of the codec-routed engine."""
     train_j = jnp.asarray(train, jnp.float32)
     setup = _build(train_j, jnp.asarray(test, jnp.float32), cfg)
-    round_fn = _make_round_fn(train_j, setup)
+    round_fn = _make_round_fn(train_j, setup, blocks=1, is_async=False,
+                              telemetry=False)
 
     def scan_chunk(state, cohorts):
         def body(st, cohort):

@@ -59,7 +59,8 @@ def make_data(users: int, items: int, density: float = 0.02, seed: int = 0):
 def _setup(train, test, cfg: FLSimConfig):
     train_j = jnp.asarray(train, jnp.float32)
     setup = _build(train_j, jnp.asarray(test, jnp.float32), cfg)
-    return train_j, setup, _make_round_fn(train_j, setup)
+    return train_j, setup, _make_round_fn(train_j, setup, blocks=1,
+                                          is_async=False, telemetry=False)
 
 
 @partial(jax.jit, static_argnames=("l2", "alpha"))

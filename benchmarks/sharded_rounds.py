@@ -96,34 +96,17 @@ def _make_sampler(train, test, cfg, rounds: int):
     import jax
     import jax.numpy as jnp
 
-    from repro.federated.simulation import (
-        _build, _make_round_fn, make_sharded_round_runner,
-    )
+    from repro.federated.simulation import _build, _make_chunk_runner
 
     train_j = jnp.asarray(train, jnp.float32)
     setup = _build(train_j, jnp.asarray(test, jnp.float32), cfg)
-    cohorts = np.resize(setup.cohorts, (rounds,) + setup.cohorts.shape[1:])
-
-    if cfg.backend == "shard":
-        run_chunk, state0 = make_sharded_round_runner(train_j, setup, cfg)
-    else:
-        round_fn = _make_round_fn(train_j, setup, cfg.cohort_shards)
-
-        def scan_chunk(state, ch):
-            def body(st, cohort):
-                st, _ = round_fn(st, cohort)
-                return st, None
-            return jax.lax.scan(body, state, ch)
-
-        compiled = jax.jit(scan_chunk)
-        state0 = setup.state0
-
-        def run_chunk(state, ch):
-            return compiled(state, jnp.asarray(ch))
+    setup = setup._replace(cohorts=np.resize(
+        setup.cohorts, (rounds,) + setup.cohorts.shape[1:]))
+    run_chunk, state0 = _make_chunk_runner(train_j, setup, cfg, False, None)
 
     def sample() -> float:
         t0 = time.perf_counter()
-        state, _ = run_chunk(state0, cohorts)
+        state, _ = run_chunk(state0, 0, rounds)
         jax.block_until_ready(state.q)
         return rounds / (time.perf_counter() - t0)
 

@@ -164,7 +164,8 @@ def phase_round_step_kernels(chk: Checks, data):
     setup = _build(train_j, test_j, cfg)
 
     def chunk(state, cohorts, x):
-        round_fn = _make_round_fn(x, setup)
+        round_fn = _make_round_fn(x, setup, blocks=1, is_async=False,
+                                  telemetry=False)
         return jax.lax.scan(lambda s, c: (round_fn(s, c)[0], None),
                             state, cohorts)
 

@@ -1,7 +1,6 @@
 from repro.federated.simulation import (
     FLSimConfig,
     SimResult,
-    make_sharded_round_runner,
     run_fcf_simulation,
     run_seed_sweep,
     run_strategy_sweep,
@@ -9,5 +8,5 @@ from repro.federated.simulation import (
 
 __all__ = [
     "FLSimConfig", "run_fcf_simulation", "SimResult",
-    "make_sharded_round_runner", "run_seed_sweep", "run_strategy_sweep",
+    "run_seed_sweep", "run_strategy_sweep",
 ]

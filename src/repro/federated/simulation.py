@@ -35,9 +35,19 @@ and the training loop is compiled end-to-end:
     and pending buffers replicate — payload-sized — while the (M, K)
     tables row-shard exactly as in ``backend="shard"``).
 
+Every engine runs one round closure, :func:`_make_round_fn`, which picks the
+synchronous or async step once and takes this round's staleness and fault
+slice only where they apply. The compiled engines ("scan", "async",
+"shard") share one chunk builder, :func:`_make_chunk_runner`: one
+``lax.scan`` body whose inputs (cohorts, staleness schedule, fault slice,
+telemetry carry) are each present only where they apply, wrapped in one
+``shard_map`` when a mesh is asked for. The python reference loop jits the
+same round closure, and both loops end a chunk with the same
+eval/checkpoint/publish boundary.
+
 Sweep entry points (:func:`run_seed_sweep`, :func:`run_strategy_sweep`)
-vectorize the scan engine with ``jax.vmap`` over per-seed server states, so a
-multi-rebuild experiment cell runs as a single compiled program.
+vectorize the same round closure with ``jax.vmap`` over per-seed server
+states, so a multi-rebuild experiment cell runs as a single compiled program.
 
 Interactions come in one of two layouts. A dense (users, items) matrix is
 laid out on the device as float32 and closed over by the round programs. A
@@ -67,7 +77,7 @@ import numpy as np
 from repro.cf.metrics import RecMetrics, evaluate_users
 from repro.cf.model import CFConfig, cf_init
 from repro.cf.server import (
-    FCFServerConfig, RoundAux, ServerState, ShardContext, server_init,
+    FCFServerConfig, ServerState, ShardContext, server_init,
     server_round_step, server_round_step_async,
 )
 from repro.checkpoint.io import (
@@ -624,387 +634,242 @@ def _cohort_block_lists(lists: UserLists, ids: jax.Array,
     return flat.reshape(m_s, b).T.astype(jnp.float32)
 
 
-def _blocked_cohort_x(train_j: jax.Array, ids: jax.Array, shards: int,
-                      num_users: int, survivors: Optional[jax.Array] = None):
-    """Lazy blocked cohort slice for the round step.
+def _cohort_x(data, ids: jax.Array, blocks: int, num_users: Optional[int],
+              survivors: Optional[jax.Array] = None, offset=0):
+    """Lazy blocked cohort slice for the round step, ``idx -> (blocks, b,
+    M_s)``.
 
-    ``ids`` is the flat (possibly padded) cohort id vector this caller owns
-    (the full padded cohort on a single device, one block of it per device
-    under ``shard_map``). Returns ``idx -> (C_local, b, M_s)`` where padded
-    user rows are zeroed — an all-zero x row solves to p=0 and contributes
-    exactly zero to every aggregate, so padding never changes the math.
+    ``ids`` are this caller's rows of the padded cohort, starting at row
+    ``offset`` of it: the whole cohort on one device (``offset`` 0), one
+    device's block under ``shard_map`` (``blocks`` 1, ``offset`` the traced
+    ``axis_index * b``). Rows at or past ``num_users`` are padding and are
+    zeroed — an all-zero x row solves to p=0 and contributes exactly zero to
+    every aggregate, so padding never changes the math. ``num_users`` is
+    None when the padded cohort has no padding rows, which traces no mask.
 
-    ``survivors`` ((total,) f32, the fault layer's padded per-slot keep
-    vector) additionally zeroes dropped/straggling users' rows — the same
-    exact-no-op mechanism as padding, composed multiplicatively with the
-    static pad mask. ``None`` compiles the historical closure untouched.
+    ``survivors`` (the fault layer's (total,) f32 keep vector over the whole
+    padded cohort) additionally zeroes dropped/straggling users' rows — the
+    same exact-no-op mechanism as padding, composed multiplicatively with
+    the static pad mask. ``None`` compiles the historical closure untouched.
     """
-    total = ids.shape[0]
-    c_local = shards
-    b = total // shards
+    rows = ids.shape[0]
+    traced = not isinstance(offset, int)    # one block of a device mesh
 
     def cohort_x(idx):
-        x = _cohort_block(train_j, ids, idx)                 # (total, M_s)
-        if num_users < total:
-            mask = (jnp.arange(total) < num_users).astype(x.dtype)
-            x = x * mask[:, None]
-        if survivors is not None:
-            x = x * survivors.astype(x.dtype)[:, None]
-        return x.reshape(c_local, b, idx.shape[0])
-
-    return cohort_x
-
-
-def _local_cohort_x(ids: jax.Array, didx: jax.Array, train_rep: jax.Array,
-                    shards: int, num_users: int,
-                    survivors: Optional[jax.Array] = None):
-    """The shard engine's per-device cohort slice, ``idx -> (1, b, M_s)``.
-
-    ``ids`` is device ``didx``'s block of the padded cohort, gathered from
-    the replicated ``train_rep``; rows past ``num_users`` and, given
-    ``survivors`` (the full replicated (shards*b,) padded keep vector), the
-    dropped users' rows are zeroed exactly as :func:`_blocked_cohort_x`
-    zeroes them on one device.
-    """
-    b = ids.shape[0]
-    padded = shards * b != num_users
-
-    def cohort_x(idx):
-        x = _cohort_block(train_rep, ids, idx)               # (b, M_s)
-        if padded:
-            pos = didx * b + jnp.arange(b)
+        x = _cohort_block(data, ids, idx)                    # (rows, M_s)
+        if num_users is not None:
+            pos = jnp.arange(rows)
+            if traced:
+                pos = offset + pos
             x = x * (pos < num_users).astype(x.dtype)[:, None]
         if survivors is not None:
-            local = jax.lax.dynamic_slice_in_dim(survivors, didx * b, b)
-            x = x * local.astype(x.dtype)[:, None]
-        return x[None]                                       # (1, b, M_s)
+            keep = survivors
+            if traced:
+                keep = jax.lax.dynamic_slice_in_dim(survivors, offset, rows)
+            x = x * keep.astype(x.dtype)[:, None]
+        return x.reshape(blocks, rows // blocks, idx.shape[0])
 
     return cohort_x
+
+
+def _padded_size(cohort_size: int, blocks: int) -> int:
+    """The cohort's size padded up to ``blocks`` equal blocks."""
+    return blocks * -(-cohort_size // blocks)
 
 
 def _pad_cohort(cohort: jax.Array, shards: int) -> jax.Array:
     """Pad a flat (B,) cohort id vector to a multiple of ``shards``.
 
     Pad entries reuse user id 0; their interaction rows are masked to zero
-    by :func:`_blocked_cohort_x` so they are exact no-ops.
+    by :func:`_cohort_x` so they are exact no-ops.
     """
     b_total = cohort.shape[0]
-    b = -(-b_total // shards)
-    return jnp.pad(cohort, (0, shards * b - b_total))
+    return jnp.pad(cohort, (0, _padded_size(b_total, shards) - b_total))
 
 
-def _make_round_fn(train_j: jax.Array, setup: _SimSetup,
-                   cohort_shards: int = 1, telemetry: bool = False,
-                   fault_on: bool = False):
-    """(state, cohort_ids (B,)) -> (state, RoundAux): one fused FL round.
+def _cohort_blocks(config: FLSimConfig) -> Tuple[int, bool]:
+    """``(blocks, on_mesh)``: the number of equal blocks each round's cohort
+    is solved in, on which the round's float semantics depend, and whether
+    the rounds run under ``shard_map``. A mesh (``backend="shard"``, or
+    ``"async"`` given ``mesh_shards``; plain async is single-device) solves
+    one block per device; otherwise async takes ``blocks_per_commit`` and
+    the other engines ``cohort_shards``."""
+    if config.backend == "shard" or (config.backend == "async"
+                                     and config.mesh_shards is not None):
+        return config.mesh_shards or len(jax.devices()), True
+    if config.backend == "async":
+        return config.blocks_per_commit, False
+    return config.cohort_shards, False
 
-    With ``fault_on`` (static) the returned step additionally consumes this
-    round's :class:`repro.faults.RoundFaults` slice: dropped/straggling
-    users are zeroed out of the cohort (exact no-op rows) and the gradient
-    renormalizes over the traced survivor count; the ``fault_on=False``
-    program is byte-for-byte the historical one.
+
+def _make_round_fn(data, setup: _SimSetup, *, blocks: int, is_async: bool,
+                   telemetry: bool, shard: Optional[ShardContext] = None):
+    """``(state, cohort, stale=None, rf=None) -> (state, RoundAux)``: one
+    fused FL round over the cohort split into ``blocks`` equal blocks.
+
+    ``is_async`` builds :func:`repro.cf.server.server_round_step_async`,
+    which commits against the snapshot ``stale`` rounds old; otherwise
+    :func:`repro.cf.server.server_round_step`. Under ``shard`` (inside
+    ``shard_map`` over ``blocks`` devices) ``cohort`` is this device's
+    (1, b) block of the padded cohort; on one device it is the flat (B,)
+    cohort.
+
+    ``rf``, this round's :class:`repro.faults.RoundFaults` slice, zeroes
+    dropped/straggling users out of the cohort (exact no-op rows) and
+    renormalizes the gradient over the traced survivor count. ``rf=None``
+    traces byte-for-byte the program without the fault layer.
     """
-    sel_cfg, srv_cfg, cf_cfg = setup.sel_cfg, setup.srv_cfg, setup.cf_cfg
+    step = server_round_step_async if is_async else server_round_step
+    n = setup.cohorts.shape[1]
+    pad_users = n if _padded_size(n, blocks) > n else None
 
-    if fault_on:
-        def faulted_round_fn(state: ServerState, cohort: jax.Array, rf):
-            num_users = cohort.shape[0]
-            ids = _pad_cohort(cohort, cohort_shards)
-            cohort_x = _blocked_cohort_x(train_j, ids, cohort_shards,
-                                         num_users, survivors=rf.survivors)
-            n_eff = jnp.sum(rf.survivors)
-            return server_round_step(
-                state, cohort_x, sel_cfg=sel_cfg, config=srv_cfg,
-                cf_cfg=cf_cfg, codec_cfg=setup.codec_cfg, num_users=n_eff,
-                telemetry=telemetry, faults=rf)
-
-        return faulted_round_fn
-
-    def round_fn(state: ServerState, cohort: jax.Array):
-        num_users = cohort.shape[0]
-        ids = _pad_cohort(cohort, cohort_shards)
-        cohort_x = _blocked_cohort_x(train_j, ids, cohort_shards, num_users)
-        return server_round_step(
-            state, cohort_x, sel_cfg=sel_cfg, config=srv_cfg, cf_cfg=cf_cfg,
-            codec_cfg=setup.codec_cfg, num_users=num_users,
-            telemetry=telemetry)
+    def round_fn(state: ServerState, cohort: jax.Array, stale=None, rf=None):
+        if shard is None:
+            ids, offset, local = _pad_cohort(cohort, blocks), 0, blocks
+        else:
+            ids = cohort.reshape(-1)
+            offset = jax.lax.axis_index(shard.axis) * ids.shape[0]
+            local = 1
+        cohort_x = _cohort_x(data, ids, local, pad_users,
+                             None if rf is None else rf.survivors, offset)
+        num_users = n if rf is None else jnp.sum(rf.survivors)
+        args = (state, cohort_x) + ((stale,) if is_async else ())
+        return step(*args, sel_cfg=setup.sel_cfg, config=setup.srv_cfg,
+                    cf_cfg=setup.cf_cfg, codec_cfg=setup.codec_cfg,
+                    num_users=num_users, shard=shard, telemetry=telemetry,
+                    faults=rf)
 
     return round_fn
 
 
-def _make_async_round_fn(train_j: jax.Array, setup: _SimSetup, blocks: int,
-                         telemetry: bool = False, fault_on: bool = False):
-    """(state, cohort (B,), staleness ()) -> (state, aux): one async round.
+def _make_chunk_runner(train, setup: _SimSetup, config: FLSimConfig,
+                       record: bool, obs: Optional[ObsConfig]):
+    """Compile the round scan of ``backend`` "scan", "async" or "shard".
 
-    ``fault_on`` mirrors :func:`_make_round_fn`: the faulted step takes a
-    trailing :class:`repro.faults.RoundFaults` argument.
-    """
-    sel_cfg, srv_cfg, cf_cfg = setup.sel_cfg, setup.srv_cfg, setup.cf_cfg
+    Returns ``(run_chunk, state0)``. ``run_chunk(state, lo, hi)`` commits
+    rounds ``[lo, hi)`` of the pre-sampled schedules as one compiled program
+    and returns ``(state, ys)``, ``ys`` the rounds'
+    :class:`repro.cf.server.RoundAux` when ``record`` (else None).
 
-    if fault_on:
-        def faulted_round_fn(state: ServerState, cohort: jax.Array,
-                             staleness: jax.Array, rf):
-            num_users = cohort.shape[0]
-            ids = _pad_cohort(cohort, blocks)
-            cohort_x = _blocked_cohort_x(train_j, ids, blocks, num_users,
-                                         survivors=rf.survivors)
-            n_eff = jnp.sum(rf.survivors)
-            return server_round_step_async(
-                state, cohort_x, staleness, sel_cfg=sel_cfg, config=srv_cfg,
-                cf_cfg=cf_cfg, codec_cfg=setup.codec_cfg, num_users=n_eff,
-                telemetry=telemetry, faults=rf)
+    One scan body serves every engine. The per-round inputs ride the scan as
+    ``xs = (cohorts, stale, rf)``: the staleness schedule only for async, the
+    :class:`repro.faults.RoundFaults` slice only with faults on. With ``obs``
+    (an *enabled* :class:`ObsConfig`) the TelemetryState rides the carry and
+    each round's packed (15,) row is a ys output. An absent input is None,
+    which leaves no leaf in the pytree, so each combination's program is
+    byte-for-byte the one it would be written alone: obs and faults off add
+    zero ops, and the benchmark's chunk programs keep their hashes
+    (``scripts/chunk_hlo.py``).
 
-        return faulted_round_fn
-
-    def round_fn(state: ServerState, cohort: jax.Array,
-                 staleness: jax.Array):
-        num_users = cohort.shape[0]
-        ids = _pad_cohort(cohort, blocks)
-        cohort_x = _blocked_cohort_x(train_j, ids, blocks, num_users)
-        return server_round_step_async(
-            state, cohort_x, staleness, sel_cfg=sel_cfg, config=srv_cfg,
-            cf_cfg=cf_cfg, codec_cfg=setup.codec_cfg, num_users=num_users,
-            telemetry=telemetry)
-
-    return round_fn
-
-
-def make_sharded_round_runner(train_j: jax.Array, setup: _SimSetup,
-                              config: FLSimConfig, record: bool = False,
-                              obs: Optional[ObsConfig] = None):
-    """Compile the FL round scan as a ``shard_map`` program over a device mesh.
-
-    Returns ``(run_chunk, state0)``: ``run_chunk(state, cohorts (R, B) np)``
-    scans R data-parallel rounds, ``state0`` is the initial server state with
-    its (M, K) tables row-sharded over the ("data",) mesh (everything else
-    replicated). Each device holds M/D rows of Q / Adam moments / BTS reward
-    buffers / codec residual and solves one cohort block of ceil(B/D) users
-    per round; per round only payload-sized tensors cross the interconnect
+    Under a mesh one ``shard_map`` over the ("data",) axis wraps the same
+    chunk. ``state0``'s (M, K) tables (Q, Adam moments, BTS reward buffers,
+    codec residual) are row-sharded over the devices, everything else is
+    replicated, and each device solves one block of ceil(B/D) users per
+    round, so per round only payload-sized tensors cross the interconnect
     (encoded Q* candidates, partial gradients, selected-row gathers).
     Trajectories are bit-identical to ``backend="scan"`` with
     ``cohort_shards=D`` (see :func:`repro.cf.server.server_round_step`).
+    Async's snapshot ring and pending-attribution buffers replicate with the
+    selector posteriors (they are payload-sized): a stale block is just a
+    block solved against an older Q*, so the collective schedule is exactly
+    the synchronous one.
 
-    With ``config.backend == "async"`` the same mesh runs the async engine:
-    the scan additionally consumes the (R,) staleness schedule (replicated),
-    the snapshot ring and pending-attribution buffers replicate alongside
-    the selector posteriors (they are payload-sized), and the returned
-    ``run_chunk(state, cohorts, staleness)`` takes the schedule slice —
-    a stale block is just a block solved against an older Q*, so the
-    collective schedule is exactly the synchronous one.
-
-    ``obs`` (an *enabled* :class:`ObsConfig`) additionally threads the
-    replicated telemetry aggregates through the scan carry and returns the
-    per-round telemetry rows from the compiled program; ``run_chunk``
-    emits them host-side after each chunk (the rows come back replicated,
-    so the host emission is mesh-safe without putting an ``io_callback``
-    inside ``shard_map``). ``obs=None`` leaves the original programs
-    byte-for-byte untouched.
+    Telemetry rows leave through one batched ``io_callback`` per chunk on
+    one device. There can be no ``io_callback`` inside ``shard_map``, so
+    under a mesh the rows come back replicated as an output and are emitted
+    on the host after the chunk.
     """
-    from jax.sharding import PartitionSpec as P
+    from jax.experimental import io_callback
 
-    from repro.launch.mesh import make_data_mesh
-    from repro.launch.sharding import fcf_state_pspecs, to_shardings
-
-    d = config.mesh_shards or len(jax.devices())
-    m = setup.cf_cfg.num_items
-    if m % d:
-        raise ValueError(
-            f"backend='shard' row-shards the (M, K) tables: num_items={m} "
-            f"must divide evenly over {d} devices")
-    mesh = make_data_mesh(d)
-    b_total = setup.cohorts.shape[1]
-    b = -(-b_total // d)                  # users per device block
-    shard_ctx = ShardContext(axis="data", num_shards=d, rows_per_shard=m // d)
-    sel_cfg, srv_cfg, cf_cfg = setup.sel_cfg, setup.srv_cfg, setup.cf_cfg
-
-    state_specs = fcf_state_pspecs(setup.state0)
-    state0 = jax.device_put(setup.state0, to_shardings(mesh, state_specs))
     is_async = config.backend == "async"
-    aux_specs = RoundAux(indices=P(), rewards=P()) if record else None
-    telemetry = obs is not None
     fault_on = config.faults is not None and config.faults.enabled
-
-    if telemetry:
-        # telemetry variants: the replicated TelemetryState rides the scan
-        # carry, every round's packed row is a replicated (15,) ys output.
-        # The non-telemetry programs below stay byte-for-byte untouched —
-        # that, not cleverness, is what makes the disabled-path bit-parity
-        # contract trivially true for the sharded engine too.
-        tel0 = telemetry_state_init(sel_cfg.num_arms)
-        tel_specs = jax.tree.map(lambda _: P(), tel0)
+    blocks, on_mesh = _cohort_blocks(config)
+    n = setup.cohorts.shape[1]
+    pad_total = _padded_size(n, blocks)
+    emitter = tel0 = shard = None
+    if obs is not None:
         emitter = make_row_emitter(obs.resolve_sink(), obs.telemetry_every)
+        tel0 = telemetry_state_init(setup.sel_cfg.num_arms)
+    tel_holder = [tel0]
+    state0 = setup.state0
+    if on_mesh:
+        from jax.sharding import PartitionSpec as P
 
-        if is_async:
-            def chunk(state, tel, cohorts_blk, stale, train_rep):
-                def body(carry, xs):
-                    st, ts = carry
-                    cohort_l, s_t = xs
-                    cohort_x = _local_cohort_x(
-                        cohort_l.reshape(-1), jax.lax.axis_index("data"),
-                        train_rep, d, b_total)
-                    st, aux = server_round_step_async(
-                        st, cohort_x, s_t, sel_cfg=sel_cfg, config=srv_cfg,
-                        cf_cfg=cf_cfg, codec_cfg=setup.codec_cfg,
-                        num_users=b_total, shard=shard_ctx, telemetry=True)
-                    ts, row = telemetry_round(
-                        ts, aux.telemetry, aux.indices, aux.rewards)
-                    ys = aux._replace(telemetry=()) if record else None
-                    return (st, ts), (ys, row)
+        from repro.launch.mesh import make_data_mesh
+        from repro.launch.sharding import fcf_state_pspecs, to_shardings
 
-                (state, tel), (ys, rows) = jax.lax.scan(
-                    body, (state, tel), (cohorts_blk, stale))
-                return state, tel, ys, rows
+        m = setup.cf_cfg.num_items
+        if m % blocks:
+            raise ValueError(
+                f"backend='shard' row-shards the (M, K) tables: num_items={m} "
+                f"must divide evenly over {blocks} devices")
+        mesh = make_data_mesh(blocks)
+        shard = ShardContext(axis="data", num_shards=blocks,
+                             rows_per_shard=m // blocks)
+        state_specs = fcf_state_pspecs(setup.state0)
+        state0 = jax.device_put(setup.state0, to_shardings(mesh, state_specs))
+    # per-user lists enter the chunk as its argument ``data_arg``, and so
+    # does the dense matrix under a mesh (replicated); on one device the
+    # dense matrix stays closed over and ``data_arg`` is None, so its
+    # programs are as they were
+    data = train if on_mesh or isinstance(train, UserLists) else None
 
-            run = jax.jit(jax.shard_map(
-                chunk, mesh=mesh,
-                in_specs=(state_specs, tel_specs,
-                          P(None, "data", None), P(), P()),
-                out_specs=(state_specs, tel_specs, aux_specs, P()),
-                check_vma=False))
-        else:
-            def chunk(state, tel, cohorts_blk, train_rep):
-                def body(carry, cohort_l):
-                    st, ts = carry
-                    cohort_x = _local_cohort_x(
-                        cohort_l.reshape(-1), jax.lax.axis_index("data"),
-                        train_rep, d, b_total)
-                    st, aux = server_round_step(
-                        st, cohort_x, sel_cfg=sel_cfg, config=srv_cfg,
-                        cf_cfg=cf_cfg, codec_cfg=setup.codec_cfg,
-                        num_users=b_total, shard=shard_ctx, telemetry=True)
-                    ts, row = telemetry_round(
-                        ts, aux.telemetry, aux.indices, aux.rewards)
-                    ys = aux._replace(telemetry=()) if record else None
-                    return (st, ts), (ys, row)
+    # the program's text holds this function's name (its HLO module is
+    # ``jit_scan_chunk``, which the benchmark's trace readers and
+    # ``scripts/chunk_hlo.py`` look for) and names its parameters after these
+    # arguments, so both are part of each chunk program's hash
+    def scan_chunk(st, tel, cohorts, stale, rf, data_arg):
+        round_fn = _make_round_fn(
+            train if data_arg is None else data_arg, setup, blocks=blocks,
+            is_async=is_async, telemetry=tel is not None, shard=shard)
 
-                (state, tel), (ys, rows) = jax.lax.scan(
-                    body, (state, tel), cohorts_blk)
-                return state, tel, ys, rows
+        def body(carry, x):
+            s, ts = carry
+            s, aux = round_fn(s, *x)
+            row = None
+            if ts is not None:
+                ts, row = telemetry_round(
+                    ts, aux.telemetry, aux.indices, aux.rewards)
+            ys = aux._replace(telemetry=()) if record else None
+            return (s, ts), (ys, row)
 
-            run = jax.jit(jax.shard_map(
-                chunk, mesh=mesh,
-                in_specs=(state_specs, tel_specs, P(None, "data", None), P()),
-                out_specs=(state_specs, tel_specs, aux_specs, P()),
-                check_vma=False))
+        (st, tel), (ys, rows) = jax.lax.scan(body, (st, tel),
+                                             (cohorts, stale, rf))
+        if rows is not None and shard is None:
+            # one BATCHED host callback per compiled chunk; the host side
+            # applies the telemetry_every rate limit
+            io_callback(emitter, None, rows, ordered=True)
+            rows = None
+        return st, tel, ys, rows
 
-        tel_holder = [jax.device_put(tel0, to_shardings(mesh, tel_specs))]
-
-        def run_chunk(state, cohorts, staleness=None):
-            cohorts = np.asarray(cohorts)
-            r = cohorts.shape[0]
-            ids = np.pad(cohorts, ((0, 0), (0, d * b - b_total)))
-            blocked = jnp.asarray(ids.reshape(r, d, b).astype(np.int32))
-            if is_async:
-                stale = jnp.asarray(np.asarray(staleness), jnp.int32)
-                state, tel, ys, rows = run(
-                    state, tel_holder[0], blocked, stale, train_j)
-            else:
-                state, tel, ys, rows = run(
-                    state, tel_holder[0], blocked, train_j)
-            tel_holder[0] = tel
-            emitter(np.asarray(rows))
-            return state, ys
-
-        return run_chunk, state0
-
-    if fault_on and is_async:
-        # faulted variants: the RoundFaults xs ride the scan replicated
-        # (P() pytree-prefix spec — survivors/corrupt are payload-sized),
-        # every device slices its own survivor block and the replicated
-        # survivor sum renormalizes the gradient identically on all shards.
-        # The fault_on=False programs below stay byte-for-byte untouched.
-        def chunk(state, cohorts_blk, stale, rf, train_rep):
-            def body(st, xs):
-                cohort_l, s_t, rf_t = xs
-                cohort_x = _local_cohort_x(
-                    cohort_l.reshape(-1), jax.lax.axis_index("data"),
-                    train_rep, d, b_total, survivors=rf_t.survivors)
-                n_eff = jnp.sum(rf_t.survivors)
-                st, aux = server_round_step_async(
-                    st, cohort_x, s_t, sel_cfg=sel_cfg, config=srv_cfg,
-                    cf_cfg=cf_cfg, codec_cfg=setup.codec_cfg,
-                    num_users=n_eff, shard=shard_ctx, faults=rf_t)
-                return st, (aux if record else None)
-
-            return jax.lax.scan(body, state, (cohorts_blk, stale, rf))
-
-        run = jax.jit(jax.shard_map(
-            chunk, mesh=mesh,
-            in_specs=(state_specs, P(None, "data", None), P(), P(), P()),
-            out_specs=(state_specs, aux_specs), check_vma=False))
-    elif fault_on:
-        def chunk(state, cohorts_blk, rf, train_rep):
-            def body(st, xs):
-                cohort_l, rf_t = xs
-                cohort_x = _local_cohort_x(
-                    cohort_l.reshape(-1), jax.lax.axis_index("data"),
-                    train_rep, d, b_total, survivors=rf_t.survivors)
-                n_eff = jnp.sum(rf_t.survivors)
-                st, aux = server_round_step(
-                    st, cohort_x, sel_cfg=sel_cfg, config=srv_cfg,
-                    cf_cfg=cf_cfg, codec_cfg=setup.codec_cfg,
-                    num_users=n_eff, shard=shard_ctx, faults=rf_t)
-                return st, (aux if record else None)
-
-            return jax.lax.scan(body, state, (cohorts_blk, rf))
-
-        run = jax.jit(jax.shard_map(
-            chunk, mesh=mesh,
-            in_specs=(state_specs, P(None, "data", None), P(), P()),
-            out_specs=(state_specs, aux_specs), check_vma=False))
-    elif is_async:
-        def chunk(state, cohorts_blk, stale, train_rep):
-            # cohorts_blk (R, 1, b) local; stale (R,) + train_rep replicated
-            def body(st, xs):
-                cohort_l, s_t = xs
-                cohort_x = _local_cohort_x(
-                    cohort_l.reshape(-1), jax.lax.axis_index("data"),
-                    train_rep, d, b_total)
-                st, aux = server_round_step_async(
-                    st, cohort_x, s_t, sel_cfg=sel_cfg, config=srv_cfg,
-                    cf_cfg=cf_cfg, codec_cfg=setup.codec_cfg,
-                    num_users=b_total, shard=shard_ctx)
-                return st, (aux if record else None)
-
-            return jax.lax.scan(body, state, (cohorts_blk, stale))
-
-        run = jax.jit(jax.shard_map(
-            chunk, mesh=mesh,
-            in_specs=(state_specs, P(None, "data", None), P(), P()),
-            out_specs=(state_specs, aux_specs), check_vma=False))
+    if shard is None:
+        run = jax.jit(scan_chunk)
     else:
-        def chunk(state, cohorts_blk, train_rep):
-            # local views: cohorts_blk (R, 1, b); train_rep replicated (N, M)
-            def body(st, cohort_l):
-                cohort_x = _local_cohort_x(
-                    cohort_l.reshape(-1), jax.lax.axis_index("data"),
-                    train_rep, d, b_total)
-                st, aux = server_round_step(
-                    st, cohort_x, sel_cfg=sel_cfg, config=srv_cfg,
-                    cf_cfg=cf_cfg, codec_cfg=setup.codec_cfg,
-                    num_users=b_total, shard=shard_ctx)
-                return st, (aux if record else None)
-
-            return jax.lax.scan(body, state, cohorts_blk)
-
+        # the blocked cohorts (R, D, b) split over the devices; telemetry,
+        # staleness, the RoundFaults slice (survivors/corrupt are
+        # payload-sized, and the replicated survivor sum renormalizes the
+        # gradient identically on all shards) and the matrix replicate
         run = jax.jit(jax.shard_map(
-            chunk, mesh=mesh,
-            in_specs=(state_specs, P(None, "data", None), P()),
-            out_specs=(state_specs, aux_specs), check_vma=False))
+            scan_chunk, mesh=mesh,
+            in_specs=(state_specs, P(), P(None, "data", None), P(), P(),
+                      P()),
+            out_specs=(state_specs, P(), P(), P()), check_vma=False))
 
-    def run_chunk(state, cohorts, staleness=None, rf=None):
-        cohorts = np.asarray(cohorts)
-        r = cohorts.shape[0]
-        ids = np.pad(cohorts, ((0, 0), (0, d * b - b_total)))
-        blocked = jnp.asarray(ids.reshape(r, d, b).astype(np.int32))
+    def run_chunk(state, lo: int, hi: int):
+        cohorts = setup.cohorts[lo:hi]
+        if shard is not None:
+            cohorts = np.pad(cohorts, ((0, 0), (0, pad_total - n))).reshape(
+                hi - lo, blocks, -1)
+        stale = rf = None
         if is_async:
-            stale = jnp.asarray(np.asarray(staleness), jnp.int32)
-            if fault_on:
-                return run(state, blocked, stale, rf, train_j)
-            return run(state, blocked, stale, train_j)
+            stale = jnp.asarray(setup.staleness[lo:hi], jnp.int32)
         if fault_on:
-            return run(state, blocked, rf, train_j)
-        return run(state, blocked, train_j)
+            rf = round_faults_xs(setup.fault_sched, lo, hi, pad_to=pad_total)
+        state, tel_holder[0], ys, rows = run(
+            state, tel_holder[0], jnp.asarray(cohorts), stale, rf, data)
+        if rows is not None:
+            emitter(np.asarray(rows))
+        return state, ys
 
     return run_chunk, state0
 
@@ -1141,9 +1006,30 @@ def run_fcf_simulation(
                 install_tracer(prev_tracer)
 
 
-def _run_single(train_j, setup, config, record, obs, csv_path) -> SimResult:
-    from jax.experimental import io_callback
+def _boundary(t: int, state: ServerState, setup: _SimSetup,
+              config: FLSimConfig, history: MetricLogger) -> int:
+    """Evaluate, checkpoint and publish at round ``t``, an eval boundary.
+    Returns 1 when the snapshot hook raised, else 0: training continues,
+    since a serving publish failure must never abort the round loop."""
+    with span("eval", round=t):
+        m = _evaluate(state.q, setup.eval_train, setup.eval_test, config)
+        history.log(t, **m.as_dict())
+    if config.checkpoint_dir is not None:
+        save_checkpoint(config.checkpoint_dir, t, state)
+    if config.snapshot_hook is None:
+        return 0
+    try:
+        with span("publish", round=t):
+            config.snapshot_hook(t, state)
+    except Exception:
+        log.exception(
+            "snapshot_hook raised at round %d; training continues (the "
+            "previously published model stays live)", t)
+        return 1
+    return 0
 
+
+def _run_single(train, setup, config, record, obs, csv_path) -> SimResult:
     fault_cfg = config.faults
     fault_on = fault_cfg is not None and fault_cfg.enabled
     crash_round = fault_cfg.crash_round if fault_on else None
@@ -1160,167 +1046,20 @@ def _run_single(train_j, setup, config, record, obs, csv_path) -> SimResult:
         setup = setup._replace(
             state0=load_checkpoint(path, like=setup.state0))
         log.info("resuming from %s at round %d", path, start_round)
-    pad_total = None
-    if fault_on:
-        use_mesh_pad = config.backend == "shard" or (
-            config.backend == "async" and config.mesh_shards is not None)
-        if use_mesh_pad:
-            shards_n = config.mesh_shards or len(jax.devices())
-        elif config.backend == "async":
-            shards_n = config.blocks_per_commit
-        else:
-            shards_n = config.cohort_shards
-        b_total = setup.cohorts.shape[1]
-        pad_total = shards_n * (-(-b_total // shards_n))
 
     history = MetricLogger(csv_path)
     state = setup.state0
     aux_chunks: List = []
     hook_failures = 0
-    emitter = None
-    tel_holder = None
-    if obs is not None:
-        emitter = make_row_emitter(obs.resolve_sink(), obs.telemetry_every)
-        tel_holder = [telemetry_state_init(setup.sel_cfg.num_arms)]
     profiler = None
     if obs is not None and obs.profile_dir is not None:
         profiler = jax.profiler.trace(obs.profile_dir)
         profiler.__enter__()
 
     try:
-        if config.backend in ("scan", "shard", "async"):
-            is_async = config.backend == "async"
-            # async shards the same way the sync engine does — but only when
-            # a mesh is asked for (mesh_shards); plain async is single-device
-            use_mesh = config.backend == "shard" or (
-                is_async and config.mesh_shards is not None)
-            if use_mesh:
-                run_chunk, state = make_sharded_round_runner(
-                    train_j, setup, config, record=record, obs=obs)
-            elif is_async:
-                round_fn = _make_async_round_fn(
-                    train_j, setup, config.blocks_per_commit,
-                    telemetry=obs is not None, fault_on=fault_on)
-
-                if obs is not None:
-                    def scan_chunk(st, tel, cohorts, stale):
-                        def body(carry, xs):
-                            s, ts = carry
-                            cohort, s_t = xs
-                            s, aux = round_fn(s, cohort, s_t)
-                            ts, row = telemetry_round(
-                                ts, aux.telemetry, aux.indices, aux.rewards)
-                            ys = (aux._replace(telemetry=())
-                                  if record else None)
-                            return (s, ts), (ys, row)
-
-                        (st, tel), (ys, rows) = jax.lax.scan(
-                            body, (st, tel), (cohorts, stale))
-                        # one BATCHED host callback per compiled chunk; the
-                        # host side applies the telemetry_every rate limit
-                        io_callback(emitter, None, rows, ordered=True)
-                        return st, tel, ys
-
-                    compiled_async = jax.jit(scan_chunk)
-
-                    def run_chunk(st, cohorts, staleness=None):
-                        st, tel_holder[0], ys = compiled_async(
-                            st, tel_holder[0], jnp.asarray(cohorts),
-                            jnp.asarray(np.asarray(staleness), jnp.int32))
-                        return st, ys
-                elif fault_on:
-                    def scan_chunk(st, cohorts, stale, rf):
-                        def body(s, xs):
-                            cohort, s_t, rf_t = xs
-                            s, aux = round_fn(s, cohort, s_t, rf_t)
-                            return s, (aux if record else None)
-                        return jax.lax.scan(body, st, (cohorts, stale, rf))
-
-                    compiled_async = jax.jit(scan_chunk)
-
-                    def run_chunk(st, cohorts, staleness=None, rf=None):
-                        return compiled_async(
-                            st, jnp.asarray(cohorts),
-                            jnp.asarray(np.asarray(staleness), jnp.int32),
-                            rf)
-                else:
-                    def scan_chunk(st, cohorts, stale):
-                        def body(s, xs):
-                            cohort, s_t = xs
-                            s, aux = round_fn(s, cohort, s_t)
-                            return s, (aux if record else None)
-                        return jax.lax.scan(body, st, (cohorts, stale))
-
-                    compiled_async = jax.jit(scan_chunk)
-
-                    def run_chunk(st, cohorts, staleness=None):
-                        return compiled_async(
-                            st, jnp.asarray(cohorts),
-                            jnp.asarray(np.asarray(staleness), jnp.int32))
-            else:
-                # per-user lists enter the chunk as its argument
-                # ``data_arg``; the dense matrix stays closed over, and
-                # ``data_arg`` is None, so its programs are as they were
-                data = train_j if isinstance(train_j, UserLists) else None
-
-                def round_fn_of(data_arg):
-                    return _make_round_fn(
-                        train_j if data_arg is None else data_arg, setup,
-                        config.cohort_shards, telemetry=obs is not None,
-                        fault_on=fault_on)
-
-                if obs is not None:
-                    def scan_chunk(st, tel, cohorts, data_arg):
-                        round_fn = round_fn_of(data_arg)
-
-                        def body(carry, cohort):
-                            s, ts = carry
-                            s, aux = round_fn(s, cohort)
-                            ts, row = telemetry_round(
-                                ts, aux.telemetry, aux.indices, aux.rewards)
-                            ys = (aux._replace(telemetry=())
-                                  if record else None)
-                            return (s, ts), (ys, row)
-
-                        (st, tel), (ys, rows) = jax.lax.scan(
-                            body, (st, tel), cohorts)
-                        io_callback(emitter, None, rows, ordered=True)
-                        return st, tel, ys
-
-                    compiled = jax.jit(scan_chunk)
-
-                    def run_chunk(st, cohorts, staleness=None):
-                        st, tel_holder[0], ys = compiled(
-                            st, tel_holder[0], jnp.asarray(cohorts), data)
-                        return st, ys
-                elif fault_on:
-                    def scan_chunk(st, cohorts, rf, data_arg):
-                        round_fn = round_fn_of(data_arg)
-
-                        def body(s, xs):
-                            cohort, rf_t = xs
-                            s, aux = round_fn(s, cohort, rf_t)
-                            return s, (aux if record else None)
-                        return jax.lax.scan(body, st, (cohorts, rf))
-
-                    compiled = jax.jit(scan_chunk)
-
-                    def run_chunk(st, cohorts, staleness=None, rf=None):
-                        return compiled(st, jnp.asarray(cohorts), rf, data)
-                else:
-                    def scan_chunk(st, cohorts, data_arg):
-                        round_fn = round_fn_of(data_arg)
-
-                        def body(s, cohort):
-                            s, aux = round_fn(s, cohort)
-                            return s, (aux if record else None)
-                        return jax.lax.scan(body, st, cohorts)
-
-                    compiled = jax.jit(scan_chunk)
-
-                    def run_chunk(st, cohorts, staleness=None):
-                        return compiled(st, jnp.asarray(cohorts), data)
-
+        if config.backend != "python":
+            run_chunk, state = _make_chunk_runner(train, setup, config,
+                                                  record, obs)
             for start, end in _chunk_bounds(config.rounds,
                                             config.eval_every):
                 if end <= start_round:
@@ -1338,14 +1077,7 @@ def _run_single(train_j, setup, config, record, obs, csv_path) -> SimResult:
                 if hi > lo:
                     with span("train_chunk", start=lo, end=hi,
                               backend=config.backend):
-                        args = [setup.cohorts[lo:hi]]
-                        if is_async:
-                            args.append(setup.staleness[lo:hi])
-                        kw = {}
-                        if fault_on:
-                            kw["rf"] = round_faults_xs(
-                                setup.fault_sched, lo, hi, pad_to=pad_total)
-                        state, aux = run_chunk(state, *args, **kw)
+                        state, aux = run_chunk(state, lo, hi)
                         if recording():
                             # the recorded span times the chunk's work, not
                             # its enqueue; the profiler's device trace
@@ -1355,66 +1087,40 @@ def _run_single(train_j, setup, config, record, obs, csv_path) -> SimResult:
                     raise SimulatedCrash(crash, config.checkpoint_dir)
                 if record:
                     aux_chunks.append(aux)
-                with span("eval", round=end):
-                    m = _evaluate(state.q, setup.eval_train,
-                                  setup.eval_test, config)
-                    history.log(end, **m.as_dict())
-                if config.checkpoint_dir is not None:
-                    save_checkpoint(config.checkpoint_dir, end, state)
-                if config.snapshot_hook is not None:
-                    try:
-                        with span("publish", round=end):
-                            config.snapshot_hook(end, state)
-                    except Exception:
-                        hook_failures += 1
-                        log.exception(
-                            "snapshot_hook raised at round %d; training "
-                            "continues (the previously published model "
-                            "stays live)", end)
+                hook_failures += _boundary(end, state, setup, config,
+                                           history)
         else:  # "python": the per-round-dispatch reference loop
-            round_fn = _make_round_fn(train_j, setup, config.cohort_shards,
-                                      telemetry=obs is not None,
-                                      fault_on=fault_on)
-            step = jax.jit(round_fn)
-            tel_step = jax.jit(telemetry_round) if obs is not None else None
+            blocks, _ = _cohort_blocks(config)
+            pad_total = _padded_size(setup.cohorts.shape[1], blocks)
+            step = jax.jit(_make_round_fn(train, setup, blocks=blocks,
+                                          is_async=False,
+                                          telemetry=obs is not None))
+            if obs is not None:
+                emitter = make_row_emitter(obs.resolve_sink(),
+                                           obs.telemetry_every)
+                tel_step = jax.jit(telemetry_round)
+                tel = telemetry_state_init(setup.sel_cfg.num_arms)
             for t in range(start_round + 1, config.rounds + 1):
                 if crash_round is not None and t == crash_round:
                     raise SimulatedCrash(crash_round, config.checkpoint_dir)
+                rf_t = None
                 if fault_on:
                     rf_t = jax.tree.map(
                         lambda a: a[0],
                         round_faults_xs(setup.fault_sched, t - 1, t,
                                         pad_to=pad_total))
-                    state, aux = step(
-                        state, jnp.asarray(setup.cohorts[t - 1]), rf_t)
-                else:
-                    state, aux = step(
-                        state, jnp.asarray(setup.cohorts[t - 1]))
+                state, aux = step(state, jnp.asarray(setup.cohorts[t - 1]),
+                                  rf=rf_t)
                 if obs is not None:
-                    tel_holder[0], row = tel_step(
-                        tel_holder[0], aux.telemetry, aux.indices,
-                        aux.rewards)
+                    tel, row = tel_step(tel, aux.telemetry, aux.indices,
+                                        aux.rewards)
                     emitter(np.asarray(row))
                     aux = aux._replace(telemetry=())
                 if record:
                     aux_chunks.append(jax.tree.map(lambda a: a[None], aux))
                 if t % config.eval_every == 0 or t == config.rounds:
-                    with span("eval", round=t):
-                        m = _evaluate(state.q, setup.eval_train,
-                                      setup.eval_test, config)
-                        history.log(t, **m.as_dict())
-                    if config.checkpoint_dir is not None:
-                        save_checkpoint(config.checkpoint_dir, t, state)
-                    if config.snapshot_hook is not None:
-                        try:
-                            with span("publish", round=t):
-                                config.snapshot_hook(t, state)
-                        except Exception:
-                            hook_failures += 1
-                            log.exception(
-                                "snapshot_hook raised at round %d; "
-                                "training continues (the previously "
-                                "published model stays live)", t)
+                    hook_failures += _boundary(t, state, setup, config,
+                                               history)
     finally:
         if profiler is not None:
             profiler.__exit__(None, None, None)
@@ -1482,8 +1188,6 @@ def run_seed_sweep(
         trains.append(train_j)
         setups.append(_build(train_j, test_j, replace(config, seed=int(s))))
     setup0 = setups[0]
-    sel_cfg, srv_cfg, cf_cfg = setup0.sel_cfg, setup0.srv_cfg, setup0.cf_cfg
-    codec_cfg = setup0.codec_cfg
     record = config.record_selections
 
     state = jax.tree.map(lambda *xs: jnp.stack(xs),
@@ -1494,12 +1198,11 @@ def run_seed_sweep(
     train_batched = jnp.stack(trains) if per_seed_data else trains[0]
 
     def scan_chunk(st, ch, train_j):
+        round_fn = _make_round_fn(train_j, setup0, blocks=1, is_async=False,
+                                  telemetry=False)
+
         def body(s, cohort):
-            def cohort_x(idx):
-                return _cohort_block(train_j, cohort, idx)
-            s, aux = server_round_step(
-                s, cohort_x, sel_cfg=sel_cfg, config=srv_cfg, cf_cfg=cf_cfg,
-                codec_cfg=codec_cfg)
+            s, aux = round_fn(s, cohort)
             return s, (aux if record else None)
         return jax.lax.scan(body, st, ch)
 

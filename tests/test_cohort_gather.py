@@ -2,9 +2,10 @@
 bit-equal to the one-step element index, and none compiles to one.
 
 ``_cohort_block`` gathers the cohort's user rows whole, then the payload
-columns as rows of that slab's transpose. The single-device and async
-engines reach it through ``_blocked_cohort_x``, the shard engine through
-``_local_cohort_x``, the vmapped seed sweep directly.
+columns as rows of that slab's transpose. Every engine reaches it through
+``_cohort_x``: with the whole padded cohort on one device, with one block
+and its row offset per device under the mesh. The vmapped seed sweep runs
+it under ``jax.vmap``.
 """
 import re
 
@@ -13,8 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.federated.simulation import (_blocked_cohort_x, _cohort_block,
-                                        _local_cohort_x, _pad_cohort)
+from repro.federated.simulation import _cohort_block, _cohort_x, _pad_cohort
 
 USERS, ITEMS = 37, 211
 
@@ -90,32 +90,36 @@ COHORTS = [(12, 1, False), (12, 3, False), (10, 4, False), (10, 4, True),
            (12, 3, True), (7, 1, True)]
 
 
+def _pad_users(num_users, ids):
+    """``num_users`` where the padded cohort has padding rows, else None,
+    as the round builder passes it."""
+    return num_users if ids.shape[0] > num_users else None
+
+
 @pytest.mark.parametrize("idx_kind", ["unsorted", "all_columns"])
 @pytest.mark.parametrize("num_users,shards,survivors", COHORTS)
-def test_blocked_cohort_x_matches_element_gather(num_users, shards,
-                                                 survivors, idx_kind):
+@pytest.mark.parametrize("placement", ["one_device", "per_device"])
+def test_cohort_x_matches_element_gather(placement, num_users, shards,
+                                         survivors, idx_kind):
+    """On one device (offset 0) the slice is the whole padded cohort's
+    block in ``shards`` blocks; per device (one block at its traced row
+    offset), the blocks stacked in device order are that block too, zeroed
+    as on one device."""
     train, ids, keep, idx, want = _cohort_case(num_users, shards, survivors,
                                                idx_kind)
-    fn = _blocked_cohort_x(train, ids, shards, num_users, survivors=keep)
-    got = jax.jit(fn)(idx)
     total = ids.shape[0]
-    assert got.shape == (shards, total // shards, idx.shape[0])
-    assert np.array_equal(np.asarray(got).reshape(total, -1), want)
-
-
-@pytest.mark.parametrize("idx_kind", ["unsorted", "all_columns"])
-@pytest.mark.parametrize("num_users,shards,survivors", COHORTS)
-def test_local_cohort_x_matches_element_gather(num_users, shards,
-                                               survivors, idx_kind):
-    """Each device's block, stacked in device order, is the whole padded
-    cohort's block, zeroed as on one device."""
-    train, ids, keep, idx, want = _cohort_case(num_users, shards, survivors,
-                                               idx_kind)
-    b = ids.shape[0] // shards
+    b = total // shards
+    pad_users = _pad_users(num_users, ids)
+    if placement == "one_device":
+        fn = _cohort_x(train, ids, shards, pad_users, survivors=keep)
+        got = jax.jit(fn)(idx)
+        assert got.shape == (shards, b, idx.shape[0])
+        assert np.array_equal(np.asarray(got).reshape(total, -1), want)
+        return
     blocks = []
     for d in range(shards):
-        fn = _local_cohort_x(ids[d * b:(d + 1) * b], jnp.int32(d), train,
-                             shards, num_users, survivors=keep)
+        fn = _cohort_x(train, ids[d * b:(d + 1) * b], 1, pad_users,
+                       survivors=keep, offset=jnp.int32(d * b))
         got = jax.jit(fn)(idx)
         assert got.shape == (1, b, idx.shape[0])
         blocks.append(np.asarray(got[0]))
@@ -157,8 +161,7 @@ def _gathers(hlo_text):
 
 @pytest.mark.parametrize("num_users,shards,survivors",
                          [(12, 1, False), (10, 4, True)])
-def test_blocked_cohort_x_compiles_to_slice_gathers(num_users, shards,
-                                                    survivors):
+def test_cohort_x_compiles_to_slice_gathers(num_users, shards, survivors):
     """No gather of the compiled closure takes single elements: the row
     stage moves whole rows of the interaction matrix, the column stage
     whole rows of the slab's transpose. The matrix is a constant and the
@@ -167,8 +170,8 @@ def test_blocked_cohort_x_compiles_to_slice_gathers(num_users, shards,
                                             "unsorted")
 
     def fn(ids, idx):
-        return _blocked_cohort_x(train, ids, shards, num_users,
-                                 survivors=keep)(idx)
+        return _cohort_x(train, ids, shards, _pad_users(num_users, ids),
+                         survivors=keep)(idx)
 
     gathers = _gathers(jax.jit(fn).lower(ids, idx).compile().as_text())
     assert gathers, "the closure compiled to no gather at all"

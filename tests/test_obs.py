@@ -426,20 +426,19 @@ def test_round_phases_are_named_in_the_compiled_chunk(backend):
     chunk program's debug info (the name stack the device trace shows)."""
     import jax
     import jax.numpy as jnp
-    from repro.federated.simulation import (
-        _build, _make_async_round_fn, _make_round_fn,
-    )
+    from repro.federated.simulation import _build, _make_round_fn
 
     train, test = _mini_data()
     cfg = _cfg(backend)
     train_j = jnp.asarray(train)
     setup = _build(train_j, jnp.asarray(test), cfg)
     cohorts = jnp.asarray(setup.cohorts[:3])
-    if backend == "async":
-        round_fn = _make_async_round_fn(train_j, setup, cfg.blocks_per_commit)
+    is_async = backend == "async"
+    round_fn = _make_round_fn(train_j, setup, blocks=1, is_async=is_async,
+                              telemetry=False)
+    if is_async:
         xs = (cohorts, jnp.asarray(setup.staleness[:3], jnp.int32))
     else:
-        round_fn = _make_round_fn(train_j, setup)
         xs = cohorts
 
     def scan_chunk(st, xs):

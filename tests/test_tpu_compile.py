@@ -23,8 +23,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.federated.simulation import (UserLists, _blocked_cohort_x,
-                                        _list_piece)
+from repro.federated.simulation import UserLists, _cohort_x, _list_piece
 from repro.kernels import fcf_grad as fcf
 from repro.kernels import moment_quant as mq
 from repro.kernels import ops
@@ -140,7 +139,7 @@ def test_cohort_gather_compiles_to_slice_gathers_for_tpu(arg, data):
     users, items, theta, m_s = COHORT_SHAPES[data]
 
     def block(train, ids, idx):
-        return _blocked_cohort_x(train, ids, 1, theta)(idx)
+        return _cohort_x(train, ids, 1, None)(idx)
 
     text = jax.jit(block).lower(arg((users, items)), arg((theta,), jnp.int32),
                                 arg((m_s,), jnp.int32)).compile().as_text()
@@ -187,7 +186,7 @@ def test_list_cohort_gather_compiles_for_tpu(arg):
     def block(indptr, indices, ids, idx):
         lists = UserLists(indptr=indptr, indices=indices,
                           num_items=ML25M_ITEMS, piece=piece)
-        return _blocked_cohort_x(lists, ids, 1, ML25M_THETA)(idx)
+        return _cohort_x(lists, ids, 1, None)(idx)
 
     text = jax.jit(block).lower(
         arg((ML25M_USERS + 1,), jnp.int32), arg((rows, chunk), jnp.int32),
